@@ -12,6 +12,9 @@ import time
 
 from repro.core import improvement
 from repro.core.trainer import RLTuneTrainer, TrainerConfig
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 ART = os.path.join(os.path.dirname(os.path.abspath(__file__)), "artifacts")
 AGENTS = os.path.join(ART, "agents")

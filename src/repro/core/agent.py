@@ -61,8 +61,11 @@ def _mlp_init(key: jax.Array, sizes: list[int], scale: float = 1.0) -> list[dict
 
 
 def _mlp_apply(layers: list[dict], x: jnp.ndarray) -> jnp.ndarray:
+    # full f32 dots: the TPU default (one bf16 pass) reorders near-tied
+    # queue logits against the CPU and the float64 reference
     for i, lyr in enumerate(layers):
-        x = x @ lyr["w"] + lyr["b"]
+        x = jnp.dot(x, lyr["w"], precision=jax.lax.Precision.HIGHEST) \
+            + lyr["b"]
         if i < len(layers) - 1:
             x = jnp.tanh(x)
     return x

@@ -1,58 +1,35 @@
 """Cost model linking the scheduler to the DL platform substrate.
 
 Job runtimes for platform-generated traces are derived from the per-arch
-roofline terms (dry-run artifacts when present, analytic model otherwise):
-a training job of `steps` steps on `chips` chips of a given GPU/TPU SKU
-takes  steps x max(compute, memory, collective) x (ref_chips / chips) /
-sku_speed  seconds.  This closes the loop: RLTune schedules the same
-architectures whose distributed execution the substrate lowers.
+analytic roofline terms (``repro.launch.roofline``): a training job of
+`steps` steps on `chips` chips of a given GPU/TPU SKU takes
+steps x max(compute, memory) x (ref_chips / chips) / sku_speed  seconds.
+This closes the loop: RLTune schedules the same architectures whose
+distributed execution the substrate lowers.
 """
 from __future__ import annotations
-
-import json
-import os
 
 import numpy as np
 
 from repro.core.types import Job
-
-_ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                          "benchmarks", "artifacts", "dryrun", "singlepod")
 
 # relative throughput of cluster SKUs vs the roofline reference chip (v5e)
 SKU_SPEED = {"v5e": 1.0, "V100": 0.63, "P100": 0.24, "T4": 0.33,
              "K80": 0.11, "M40": 0.15, "any": 0.5}
 
 
-def _load_terms(arch: str, shape: str) -> dict | None:
-    path = os.path.join(_ARTIFACTS, f"{arch}__{shape}.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            d = json.load(f)
-        return {"compute_s": d["compute_s"], "memory_s": d["memory_s"],
-                "collective_s": d["collective_s"], "chips": d["chips"]}
-    return None
-
-
 def step_time(arch: str, shape: str = "train_4k", chips: int = 256,
               sku: str = "v5e") -> float:
     """Roofline-bound step time (s) for (arch, shape) on `chips` chips."""
-    terms = _load_terms(arch, shape)
-    if terms is None:
-        from repro.configs import get_config
-        from repro.launch.roofline import analytic_cost, roofline_terms
-        from repro.models.lm import LM
-        cfg = get_config(arch)
-        ana = analytic_cost(cfg, shape, chips=256, model=LM(cfg))
-        terms = {**roofline_terms(ana["flops_per_chip"],
-                                  ana["hbm_bytes_per_chip"], 0.0),
-                 "chips": 256}
-    # production pipelines reduce-scatter + overlap collectives; the CPU-dry-run
-    # collective term is a known 10-16x upper bound (EXPERIMENTS.md §Roofline),
-    # so weight it down rather than let it dominate job runtimes
-    bound = max(terms["compute_s"], terms["memory_s"],
-                0.1 * terms["collective_s"])
-    return bound * terms["chips"] / max(chips, 1) / SKU_SPEED.get(sku, 0.5)
+    from repro.configs import get_config
+    from repro.launch.roofline import analytic_cost, roofline_terms
+    from repro.models.lm import LM
+    cfg = get_config(arch)
+    ana = analytic_cost(cfg, shape, chips=256, model=LM(cfg))
+    terms = roofline_terms(ana["flops_per_chip"], ana["hbm_bytes_per_chip"],
+                           0.0)
+    bound = max(terms["compute_s"], terms["memory_s"])
+    return bound * 256 / max(chips, 1) / SKU_SPEED.get(sku, 0.5)
 
 
 def platform_job_runtime(arch: str, num_gpus: int, sku: str,

@@ -9,7 +9,9 @@ allocation layers so the spread-vs-pack choice accounts for upcoming demand
 
 The paper uses CVXPY + GLPK_MI; this container has no GLPK, so we solve the
 identical formulation with `scipy.optimize.milp` (HiGHS, also exact MI).  A
-greedy fragmentation-aware fallback handles solver absence/failure.
+solve that ends without an optimum (time limit, infeasible) falls back to a
+greedy fragmentation-aware choice, reported as ``used_solver=False`` so the
+engine counts it as a fallback; a solver exception propagates.
 
 Constraint-skeleton memoization
 -------------------------------
@@ -51,11 +53,7 @@ import threading
 
 import numpy as np
 
-try:  # pragma: no cover - import guard
-    from scipy.optimize import Bounds, LinearConstraint, milp
-    _HAVE_SCIPY = True
-except Exception:  # pragma: no cover
-    _HAVE_SCIPY = False
+from scipy.optimize import Bounds, LinearConstraint, milp
 
 from repro.core.cluster import ClusterState, Placement, _job_shape
 from repro.core.types import Job
@@ -153,10 +151,8 @@ def choose_allocation(
         if hit is not None:
             return hit
 
-    if use_solver and _HAVE_SCIPY:
-        res = _solve_milp(cluster, job, ways, lookahead, weights)
-    else:
-        res = None
+    res = _solve_milp(cluster, job, ways, lookahead, weights) \
+        if use_solver else None
     if res is None:
         res = _greedy_choice(cluster, job, ways, lookahead, weights)
     if cache is not None:
@@ -314,19 +310,16 @@ def _solve_milp(
     # one concatenated constraint (equality block first — same row order as
     # the reference); scipy's per-LinearConstraint conversion overhead makes
     # a two-constraint split measurably slower than this single concat
-    try:
-        res = milp(
-            c=sk.c,
-            constraints=LinearConstraint(
-                np.concatenate([A_eq, A]),
-                np.concatenate([eq_lb, sk.row_lb]),
-                np.concatenate([eq_ub, sk.row_ub])),
-            integrality=sk.integrality,
-            bounds=Bounds(sk.lb, sk.ub),
-            options={"time_limit": 2.0, "presolve": True},
-        )
-    except Exception:  # pragma: no cover - solver hiccup
-        return None
+    res = milp(
+        c=sk.c,
+        constraints=LinearConstraint(
+            np.concatenate([A_eq, A]),
+            np.concatenate([eq_lb, sk.row_lb]),
+            np.concatenate([eq_ub, sk.row_ub])),
+        integrality=sk.integrality,
+        bounds=Bounds(sk.lb, sk.ub),
+        options={"time_limit": 2.0, "presolve": True},
+    )
     if not res.success or res.x is None:
         return None
     x = res.x[0]
@@ -425,16 +418,13 @@ def _solve_milp_reference(
         zc = -(0.5 ** (k + 1)) * lj.num_gpus
         c[zvar(k)] = zc if weights is None else zc * weights[k]
 
-    try:
-        res = milp(
-            c=c,
-            constraints=LinearConstraint(np.vstack(A_rows), np.array(lbs), np.array(ubs)),
-            integrality=integrality,
-            bounds=Bounds(lb, ub),
-            options={"time_limit": 2.0, "presolve": True},
-        )
-    except Exception:  # pragma: no cover - solver hiccup
-        return None
+    res = milp(
+        c=c,
+        constraints=LinearConstraint(np.vstack(A_rows), np.array(lbs), np.array(ubs)),
+        integrality=integrality,
+        bounds=Bounds(lb, ub),
+        options={"time_limit": 2.0, "presolve": True},
+    )
     if not res.success or res.x is None:
         return None
     x = res.x[0]

@@ -9,9 +9,8 @@ bucket, so ``jax.jit`` compiles once per bucket (log2 many shapes across a
 whole run) instead of once per distinct queue depth.  Batches beyond the
 largest bucket are scored in bucket-size chunks.
 
-Off-TPU the kernel auto-selects interpret mode (same convention as
-``kernels.ops``), which keeps the path importable and correct anywhere the
-jax toolchain exists; the MXU win only materializes on real hardware.  The
+On the CPU backend the kernel runs in the Pallas interpreter (the
+``kernels.ops`` convention); on a TPU it is always compiled.  The
 scorer is opt-in end to end — nothing routes through it unless a caller
 passes one to ``RLPrioritizer(deep_scorer=...)``.
 """
@@ -31,6 +30,26 @@ def bucket_for(n: int, *, lo: int = MIN_BUCKET, hi: int = MAX_BUCKET) -> int:
     while b < n and b < hi:
         b <<= 1
     return b
+
+
+def run_bucketed(fn, rows: np.ndarray, *, lo: int = MIN_BUCKET,
+                 hi: int = MAX_BUCKET) -> np.ndarray:
+    """Apply a row-wise ``fn(x_pad, m)`` to (n, F) rows in chunks of at most
+    ``hi`` rows, each zero-padded to its power-of-two bucket so that a
+    jitted ``fn`` compiles once per bucket.  ``m`` is the chunk's count of
+    real rows; the padded rows of each result are sliced away."""
+    rows = np.asarray(rows, dtype=np.float32)
+    outs = []
+    for lo_row in range(0, rows.shape[0], hi):
+        chunk = rows[lo_row:lo_row + hi]
+        m = chunk.shape[0]
+        x_pad = np.zeros((bucket_for(m, lo=lo, hi=hi), rows.shape[1]),
+                         dtype=np.float32)
+        x_pad[:m] = chunk
+        outs.append(np.asarray(fn(x_pad, m), dtype=np.float32)[:m])
+    if not outs:
+        return np.zeros((0,), dtype=np.float32)
+    return np.concatenate(outs)
 
 
 class BucketedScorer:
@@ -65,18 +84,9 @@ class BucketedScorer:
     def score(self, feats: np.ndarray) -> np.ndarray:
         """(n, F) float32 rows -> (n,) float32 logits (masked rows never
         leak: padding is scored at -1e9 and sliced away)."""
-        feats = np.asarray(feats, dtype=np.float32)
-        n = feats.shape[0]
-        if n == 0:
-            return np.zeros((0,), dtype=np.float32)
-        out = np.empty((n,), dtype=np.float32)
-        for lo in range(0, n, self.max_bucket):
-            chunk = feats[lo:lo + self.max_bucket]
-            m = chunk.shape[0]
-            b = bucket_for(m, hi=self.max_bucket)
-            x_pad = np.zeros((b, feats.shape[1]), dtype=np.float32)
-            x_pad[:m] = chunk
-            mask = np.zeros((b,), dtype=np.float32)
+        def run(x_pad: np.ndarray, m: int) -> np.ndarray:
+            mask = np.zeros((x_pad.shape[0],), dtype=np.float32)
             mask[:m] = 1.0
-            out[lo:lo + m] = self._score_bucket(x_pad, mask)[:m]
-        return out
+            return self._score_bucket(x_pad, mask)
+
+        return run_bucketed(run, feats, hi=self.max_bucket)

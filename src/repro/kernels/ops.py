@@ -1,7 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handle layout plumbing (GQA broadcast, head-dim padding, chunk padding) and
-auto-select interpret mode off-TPU so the same call sites work everywhere.
+Handle layout plumbing (GQA broadcast, head-dim padding, chunk padding).
+Interpret mode is the default only on the CPU backend, where the Pallas
+interpreter is the sole way to run a kernel; on a TPU the kernel is always
+compiled unless the caller passes ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -18,7 +20,7 @@ from repro.kernels.ssd_scan import ssd_scan_bh
 def _interpret(flag: bool | None) -> bool:
     if flag is not None:
         return flag
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_last(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:

@@ -17,19 +17,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.policy_mlp import dense
+
 
 def _predict_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
                     o_ref):
     x = x_ref[...].astype(jnp.float32)
-    h = jnp.tanh(jax.lax.dot_general(
-        x, w1_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b1_ref[...])
-    h = jnp.tanh(jax.lax.dot_general(
-        h, w2_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b2_ref[...])
-    out = jax.lax.dot_general(
-        h, w3_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b3_ref[...]
+    h = jnp.tanh(dense(x, w1_ref, b1_ref))
+    h = jnp.tanh(dense(h, w2_ref, b2_ref))
+    out = dense(h, w3_ref, b3_ref)
     o_ref[...] = out.astype(o_ref.dtype)
 
 

@@ -57,6 +57,12 @@ PREDICT_FEATURES = NUM_FEATURES + NUM_CONTEXT
 #: emit an inf/NaN reservation
 RESID_CLAMP = 4.0
 
+#: kernel batches are padded to power-of-two row buckets in this range, so
+#: a stream compiles the fused predictor once per bucket rather than once
+#: per distinct window size; longer batches run in chunks of the largest
+KERNEL_MIN_ROWS = 8
+KERNEL_MAX_ROWS = 4096
+
 
 def _gpu_bucket(num_gpus: int) -> int:
     """Power-of-two GPU-count bucket (1, 2, 3-4, 5-8, ...)."""
@@ -314,13 +320,13 @@ class RuntimePredictor(EngineHooks):
         return X
 
     def _forward(self, X: np.ndarray) -> np.ndarray:
-        if self.use_kernel:
-            try:
-                from repro.kernels.ops import predict_mlp as _kernel
-                return np.asarray(_kernel(X, self.mlp.params))
-            except Exception:  # noqa: BLE001 — no jax: numpy path is exact
-                self.use_kernel = False
-        return self.mlp.forward(X)
+        if not self.use_kernel:
+            return self.mlp.forward(X)
+        from repro.kernels.batch_score import run_bucketed
+        from repro.kernels.ops import predict_mlp
+        return run_bucketed(
+            lambda x_pad, _m: predict_mlp(x_pad, self.mlp.params), X,
+            lo=KERNEL_MIN_ROWS, hi=KERNEL_MAX_ROWS)
 
     # ---------------------------------------------------------- prediction --
     def predict_quantiles(self, jobs: list[Job],

@@ -370,8 +370,9 @@ class EngineSnapshot:
 
     @property
     def milp_fallback_ratio(self) -> float:
-        """Fraction of solver-eligible allocations that took the degraded
-        greedy path; 0.0 when the solver was never eligible."""
+        """Fraction of solver-eligible allocations that took the greedy
+        path (degradation breaker open, or a solve that ended without an
+        optimum); 0.0 when the solver was never eligible."""
         return self.milp_fallbacks / max(self.milp_calls
                                          + self.milp_fallbacks, 1)
 
@@ -923,25 +924,24 @@ class SchedulerEngine:
                     self.milp_fallbacks += 1
             else:
                 timed = len(ways) > 1
-        if use_solver and len(ways) > 1:
-            self.milp_calls += 1
-        if not timed:
-            res = choose_allocation(self.cluster, job, ways, queue_rest,
-                                    lookahead_k=self.lookahead_k,
-                                    use_solver=use_solver,
-                                    durations=durations)
-            return res.placement
-        t_solve = time.perf_counter()
+        t_solve = time.perf_counter() if timed else 0.0
         res = choose_allocation(self.cluster, job, ways, queue_rest,
                                 lookahead_k=self.lookahead_k,
-                                use_solver=True, durations=durations)
-        if time.perf_counter() - t_solve > deg.milp_budget_s:
-            self._deg_slow_streak += 1
-            if self._deg_slow_streak >= deg.trip_after:
-                self._deg_fallback_open = deg.reset_after_decisions
+                                use_solver=use_solver, durations=durations)
+        if use_solver and len(ways) > 1:
+            # a solve that ended without an optimum went greedy: a fallback
+            if res.used_solver:
+                self.milp_calls += 1
+            else:
+                self.milp_fallbacks += 1
+        if timed:
+            if time.perf_counter() - t_solve > deg.milp_budget_s:
+                self._deg_slow_streak += 1
+                if self._deg_slow_streak >= deg.trip_after:
+                    self._deg_fallback_open = deg.reset_after_decisions
+                    self._deg_slow_streak = 0
+            else:
                 self._deg_slow_streak = 0
-        else:
-            self._deg_slow_streak = 0
         return res.placement
 
     # -- EASY backfill: earliest start for the reserved job -----------------

@@ -62,8 +62,6 @@ def pipeline_forward(stage_fn: Callable, h: jax.Array, stage_params,
 def make_pipelined_apply(stage_fn: Callable, mesh, *, axis_name: str = "pod",
                          num_microbatches: int = 4):
     """Wrap a per-stage layer fn into a full pipelined apply via shard_map."""
-    from jax.experimental.shard_map import shard_map
-
     S = mesh.shape[axis_name]
 
     def apply(stacked_params, h):
@@ -75,8 +73,8 @@ def make_pipelined_apply(stage_fn: Callable, mesh, *, axis_name: str = "pod",
                                     num_microbatches=num_microbatches)
 
         pspec = jax.tree.map(lambda _: PS(axis_name), stacked_params)
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(pspec, PS()), out_specs=PS(),
-                         check_rep=False)(stacked_params, h)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(pspec, PS()), out_specs=PS(),
+                             check_vma=False)(stacked_params, h)
 
     return apply

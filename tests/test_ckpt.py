@@ -110,7 +110,8 @@ def test_elastic_reshard_subprocess(tmp_path, request):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as PS
 from repro.ckpt import load_checkpoint
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 target = {{"a": {{"w": jax.ShapeDtypeStruct((8, 16), jnp.float32),
                "b": jax.ShapeDtypeStruct((16,), jnp.bfloat16)}},
           "step": jax.ShapeDtypeStruct((), jnp.int32)}}

@@ -82,7 +82,8 @@ def test_ssd_init_state_consistency():
                                rtol=2e-3)
 
 
-@pytest.mark.parametrize("Q,F,H1,H2", [(256, 8, 64, 32), (128, 8, 32, 16)])
+@pytest.mark.parametrize("Q,F,H1,H2", [(256, 8, 64, 32), (128, 8, 32, 16),
+                                     (4096, 8, 64, 32)])
 def test_policy_mlp_sweep(Q, F, H1, H2):
     ks = jax.random.split(KEY, 7)
     x = rand(ks[0], (Q, F))
@@ -139,3 +140,15 @@ def test_chunked_attention_equals_full():
         want = _sdpa_full(q, k, v, mask)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+def test_interpret_default_follows_backend(monkeypatch):
+    """Interpret mode is the default only on the CPU backend: a TPU process
+    compiles every kernel unless the caller asks for the interpreter."""
+    assert ops._interpret(None) is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._interpret(None) is False
+    assert ops._interpret(True) is True
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    assert ops._interpret(None) is True
+    assert ops._interpret(False) is False

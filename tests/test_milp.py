@@ -136,3 +136,49 @@ def test_solver_feasibility_property(gpus, n_look):
     assert sum(res.placement.values()) == gpus
     for n, g in res.placement.items():
         assert g <= c.free_gpus[n]
+
+
+def test_failed_solve_is_a_counted_fallback(monkeypatch):
+    """A solve that ends without an optimum (time limit, infeasible) takes
+    the greedy way and the engine counts it as a fallback, not a call."""
+    import types
+
+    import repro.core.milp as milp_mod
+    from repro.core import PolicyPrioritizer, generate_trace, make_policy
+    from repro.sched import SchedulerEngine
+
+    monkeypatch.setattr(milp_mod, "milp", lambda **_: types.SimpleNamespace(
+        success=False, x=None, status=1, message="Time limit reached"))
+    c = ClusterState(make_cluster("helios"))
+    for i in range(8):                  # partly filled: spread != pack
+        c.allocate(mk(100 + i, 6), {i: 6})
+    j = mk(0, 4)
+    ways = c.candidate_ways(j)
+    assert len(ways) > 1
+    assert not choose_allocation(c, j, ways, [mk(1, 8)]).used_solver
+
+    eng = SchedulerEngine(make_cluster("helios"),
+                          PolicyPrioritizer(make_policy("fcfs")),
+                          allocator="milp")
+    eng.submit([x.clone_pending() for x in generate_trace("helios", 40)])
+    eng.drain()
+    assert eng.done
+    assert eng.milp_calls == 0 and eng.milp_fallbacks > 0
+    assert eng.snapshot().milp_fallback_ratio == 1.0
+
+
+def test_solver_exception_propagates(monkeypatch):
+    """A solver error is raised, never turned into a silent greedy pick."""
+    import repro.core.milp as milp_mod
+
+    def boom(**_):
+        raise RuntimeError("solver crashed")
+
+    monkeypatch.setattr(milp_mod, "milp", boom)
+    c = ClusterState(make_cluster("helios"))
+    for i in range(8):
+        c.allocate(mk(100 + i, 6), {i: 6})
+    j = mk(0, 4)
+    with pytest.raises(RuntimeError, match="solver crashed"):
+        choose_allocation(c, j, c.candidate_ways(j), [mk(1, 8)],
+                          solution_cache=False)

@@ -12,6 +12,7 @@ from repro.core.prioritizer import PolicyPrioritizer
 from repro.core.types import ClusterSpec, NodeSpec
 from repro.predict import (CONTEXT_NAMES, PREDICT_FEATURES, OverrunPolicy,
                            QuantileMLP, RunningMeanBaseline, RuntimePredictor)
+from repro.predict.predictor import KERNEL_MAX_ROWS
 from repro.sched import (SchedulerEngine, get_scenario, list_scenarios,
                          run_scenario)
 
@@ -134,6 +135,34 @@ def test_kernel_forward_matches_numpy():
     out = np.asarray(predict_mlp(X, mlp.params))
     assert out.shape == (6, 2)
     assert np.allclose(out, mlp.forward(X), atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [1, 7, 9, 600, KERNEL_MAX_ROWS + 1])
+def test_kernel_batches_pad_to_buckets(n):
+    """The kernel path pads each batch to a power-of-two row bucket (and
+    chunks beyond the largest); the real rows match the numpy forward."""
+    p = RuntimePredictor(use_kernel=True, seed=2)
+    rng = np.random.default_rng(n)
+    p.mlp.params["w3"][:] = rng.normal(0, 0.1, p.mlp.params["w3"].shape)
+    X = rng.normal(0, 1, (n, PREDICT_FEATURES)).astype(np.float32)
+    out = p._forward(X)
+    assert out.shape == (n, 2)
+    assert np.allclose(out, p.mlp.forward(X), atol=1e-5)
+
+
+def test_kernel_failure_raises(monkeypatch):
+    """A failing predictor kernel raises; the predictor never drops to
+    numpy behind the caller's back."""
+    import repro.kernels.ops as ops
+
+    def boom(*_a, **_k):
+        raise RuntimeError("kernel failed")
+
+    monkeypatch.setattr(ops, "predict_mlp", boom)
+    p = RuntimePredictor(use_kernel=True)
+    with pytest.raises(RuntimeError, match="kernel failed"):
+        p.predict_quantiles([mk(0, 2)])
+    assert p.use_kernel
 
 
 def test_context_features_shape():

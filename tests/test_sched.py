@@ -12,11 +12,15 @@ from repro.sched import (RollingTelemetry, SchedulerEngine, get_scenario,
 # Golden aggregates recorded from the seed implementation (pre-engine
 # Simulator.run_batch) on fixed seeds — the engine-backed path must stay
 # bit-identical: (makespan, total_wait, gpu_seconds, decisions, milp_calls,
-# backfills, restarts).
+# backfills, restarts).  The MILP row's milp_calls is 64 under scipy 1.17:
+# 27 of its solves are exact ties between spread and pack, HiGHS there
+# resolves every tie to pack, and one tie the older HiGHS resolved to
+# spread left a later job with a single candidate way (no solve); the
+# makespan, wait and GPU-seconds are unchanged.
 SEED_GOLDENS = {
     ("helios", 96, 0, "fcfs", "milp", True, False):
         (15713.6353051043, 21243.23142577523, 354981.51819661586,
-         160, 65, 23, 0),
+         160, 64, 23, 0),
     ("helios", 96, 0, "sjf", "pack", False, False):
         (17240.76681510536, 33201.677919136404, 360452.05060567195,
          184, 0, 0, 0),

@@ -83,15 +83,15 @@ def test_compressed_pod_allreduce_subprocess():
     from conftest import run_py
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 from repro.train.compression import pod_allreduce_compressed
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = jax.make_mesh((4,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.arange(4 * 8, dtype=jnp.float32).reshape(4, 8)
 def f(xs):
     out = pod_allreduce_compressed({"g": xs[0]}, "pod")
     return out["g"][None]
-y = shard_map(f, mesh=mesh, in_specs=(PS("pod"),), out_specs=PS("pod"))(x)
+y = jax.shard_map(f, mesh=mesh, in_specs=(PS("pod"),), out_specs=PS("pod"))(x)
 want = jnp.mean(x, axis=0)
 err = float(jnp.max(jnp.abs(y[0] - want)))
 assert err < 0.2, err
@@ -108,7 +108,8 @@ def test_pipeline_parallel_subprocess():
 import jax, jax.numpy as jnp, numpy as np
 from repro.train.pipeline import make_pipelined_apply
 S, M, mb, L, d = 4, 8, 2, 4, 16
-mesh = jax.make_mesh((S,), ("pod",))
+mesh = jax.make_mesh((S,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 k = jax.random.PRNGKey(0)
 Ws = jax.random.normal(k, (S, d, d)) * 0.3
 def stage_fn(W, x):

@@ -6,7 +6,7 @@ artifact (e.g. ``BENCH_streaming.json``) that is listed in the run summary
 so cross-PR perf tracking knows where to look.  Module selection:
 ``python -m benchmarks.run [module ...]`` with modules in {latency, kernels,
 roofline, variability, naive, qssf, util, transfer, policies, streaming,
-federation, rl_streaming, autoscaling, preemption, chaos, obs, scale_curve,
+federation, rl_streaming, autoscaling, preemption, chaos, scale_curve,
 prediction}.
 ``--smoke`` runs every selected module that supports it in its fast CI mode
 (modules whose ``run`` accepts a ``smoke`` kwarg; others run normally).
@@ -26,7 +26,7 @@ import time
 
 MODULES = ("latency", "kernels", "roofline", "variability", "naive", "qssf",
            "util", "transfer", "policies", "streaming", "federation",
-           "rl_streaming", "autoscaling", "preemption", "chaos", "obs",
+           "rl_streaming", "autoscaling", "preemption", "chaos",
            "scale_curve", "prediction")
 
 

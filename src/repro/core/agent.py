@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.features import CV_SIZE, MAX_QUEUE_SIZE, OV_SIZE
+from repro.obs.spans import span
 
 Params = dict[str, Any]
 
@@ -227,23 +228,26 @@ class PPOAgent:
     def act(self, ov: np.ndarray, cv: np.ndarray, mask: np.ndarray,
             explore: bool = True, record: bool = True) -> tuple[int, np.ndarray]:
         """Returns (chosen index, full logits) and records the step."""
-        if explore:
-            self._key, sub = jax.random.split(self._key)
-            out = policy_step(self.params, jnp.asarray(ov), jnp.asarray(cv),
-                              jnp.asarray(mask), sub)
-            action = int(out["action"])
-            if record:
-                self._traj["ov"].append(ov)
-                self._traj["cv"].append(cv)
-                self._traj["mask"].append(mask)
-                self._traj["action"].append(action)
-                self._traj["logp"].append(float(out["logp"]))
-                self._traj["value"].append(float(out["value"]))
-            return action, np.asarray(out["logits"])
-        order = greedy_step(self.params, jnp.asarray(ov), jnp.asarray(mask))
-        logits = np.zeros(mask.shape, dtype=np.float32)
-        logits[np.asarray(order)] = -np.arange(len(mask), dtype=np.float32)
-        return int(order[0]), logits
+        with span("rank.actor"):
+            if explore:
+                self._key, sub = jax.random.split(self._key)
+                out = policy_step(self.params, jnp.asarray(ov),
+                                  jnp.asarray(cv), jnp.asarray(mask), sub)
+                action = int(out["action"])
+                if record:
+                    self._traj["ov"].append(ov)
+                    self._traj["cv"].append(cv)
+                    self._traj["mask"].append(mask)
+                    self._traj["action"].append(action)
+                    self._traj["logp"].append(float(out["logp"]))
+                    self._traj["value"].append(float(out["value"]))
+                return action, np.asarray(out["logits"])
+            order = greedy_step(self.params, jnp.asarray(ov),
+                                jnp.asarray(mask))
+            logits = np.zeros(mask.shape, dtype=np.float32)
+            logits[np.asarray(order)] = -np.arange(len(mask),
+                                                   dtype=np.float32)
+            return int(order[0]), logits
 
     # -------------------------------------------------------------- update ----
     def _run_update(self, cat: dict[str, list], rets: np.ndarray,

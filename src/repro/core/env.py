@@ -33,6 +33,7 @@ from repro.core.features import (CV_SIZE, MAX_QUEUE_SIZE, OV_SIZE,
                                  sample_features)
 from repro.core.policies import Policy
 from repro.core.types import Job
+from repro.obs.spans import span
 
 
 @dataclasses.dataclass
@@ -94,42 +95,48 @@ class RLPrioritizer:
 
     def _rank(self, jobs, cluster, now, fields) -> list[int]:
         n = min(len(jobs), MAX_QUEUE_SIZE)
+        deep = self.deep_scorer is not None and len(jobs) > MAX_QUEUE_SIZE
         tail_logits = None
-        if self.deep_scorer is not None and len(jobs) > MAX_QUEUE_SIZE:
-            # one FBM pass over the whole window: the head state is built
-            # from the exact rows build_state would produce (same feats ->
-            # same act), and the tail rows are batch-scored through the
-            # shape-bucketed fused-MLP kernel
-            feats = build_features(jobs, cluster, now,
-                                   use_estimates=self.use_estimates,
-                                   fields=fields)
-            if self.raw_features:
-                ov_full = feats[:, :OV_SIZE]
-            else:
-                ov_full, _ = sample_features(feats, cluster)
-            mask = np.zeros((MAX_QUEUE_SIZE,), dtype=np.float32)
-            mask[:n] = 1.0
-            ov = pad_to_queue(ov_full, OV_SIZE)
-            cv = pad_to_queue(critic_features(feats), CV_SIZE)
-            tail_logits = self.deep_scorer.score(ov_full[n:])
-        else:
-            ov, cv, mask = build_state(jobs, cluster, now,
+        with span("rank.features", rows=len(jobs)):
+            if deep:
+                # one FBM pass over the whole window: the head state is
+                # built from the exact rows build_state would produce
+                # (same feats -> same act), and the tail rows are
+                # batch-scored through the shape-bucketed fused-MLP kernel
+                feats = build_features(jobs, cluster, now,
                                        use_estimates=self.use_estimates,
-                                       raw=self.raw_features, fields=fields)
+                                       fields=fields)
+                if self.raw_features:
+                    ov_full = feats[:, :OV_SIZE]
+                else:
+                    ov_full, _ = sample_features(feats, cluster)
+                mask = np.zeros((MAX_QUEUE_SIZE,), dtype=np.float32)
+                mask[:n] = 1.0
+                ov = pad_to_queue(ov_full, OV_SIZE)
+                cv = pad_to_queue(critic_features(feats), CV_SIZE)
+            else:
+                ov, cv, mask = build_state(jobs, cluster, now,
+                                           use_estimates=self.use_estimates,
+                                           raw=self.raw_features,
+                                           fields=fields)
+        if deep:
+            tail_logits = self.deep_scorer.score(ov_full[n:])
         action, logits = self.agent.act(ov, cv, mask, explore=self.explore,
                                         record=self.explore and self.record)
-        order = list(np.argsort(-logits[:n], kind="stable"))
-        if action < n:
-            order.remove(action)
-            order.insert(0, action)
-        if tail_logits is not None:
-            # deep-window mode: tail ordered by the bucketed scorer
-            # (stable argsort keeps FIFO among exact ties)
-            order += [int(n + i)
-                      for i in np.argsort(-tail_logits, kind="stable")]
-        else:
-            # jobs beyond the fixed-size window keep FIFO order at the tail
-            order += list(range(n, len(jobs)))
+        with span("rank.order"):
+            order = list(np.argsort(-logits[:n], kind="stable"))
+            if action < n:
+                order.remove(action)
+                order.insert(0, action)
+            if tail_logits is not None:
+                # deep-window mode: tail ordered by the bucketed scorer
+                # (stable argsort keeps FIFO among exact ties)
+                order += [int(n + i)
+                          for i in np.argsort(-tail_logits, kind="stable")]
+            else:
+                # jobs beyond the fixed-size window keep FIFO order at the
+                # tail
+                order += list(range(n, len(jobs)))
         return order
 
     def observe_finish(self, job: Job) -> None:

@@ -57,6 +57,7 @@ from scipy.optimize import Bounds, LinearConstraint, milp
 
 from repro.core.cluster import ClusterState, Placement, _job_shape
 from repro.core.types import Job
+from repro.obs.spans import span
 
 
 @dataclasses.dataclass
@@ -310,16 +311,17 @@ def _solve_milp(
     # one concatenated constraint (equality block first — same row order as
     # the reference); scipy's per-LinearConstraint conversion overhead makes
     # a two-constraint split measurably slower than this single concat
-    res = milp(
-        c=sk.c,
-        constraints=LinearConstraint(
-            np.concatenate([A_eq, A]),
-            np.concatenate([eq_lb, sk.row_lb]),
-            np.concatenate([eq_ub, sk.row_ub])),
-        integrality=sk.integrality,
-        bounds=Bounds(sk.lb, sk.ub),
-        options={"time_limit": 2.0, "presolve": True},
-    )
+    with span("milp.solve"):
+        res = milp(
+            c=sk.c,
+            constraints=LinearConstraint(
+                np.concatenate([A_eq, A]),
+                np.concatenate([eq_lb, sk.row_lb]),
+                np.concatenate([eq_ub, sk.row_ub])),
+            integrality=sk.integrality,
+            bounds=Bounds(sk.lb, sk.ub),
+            options={"time_limit": 2.0, "presolve": True},
+        )
     if not res.success or res.x is None:
         return None
     x = res.x[0]

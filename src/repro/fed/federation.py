@@ -32,12 +32,12 @@ percentiles and per-cluster ``BatchResult``s.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import itertools
 import math
 import os
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Iterable, Sequence
 
@@ -49,6 +49,7 @@ from repro.core.prioritizer import PolicyPrioritizer, Prioritizer
 from repro.core.types import ClusterSpec, Job
 from repro.fed.router import ClusterInfo, ClusterView, Router, make_router
 from repro.fed.scenarios import FleetRun, get_fleet_scenario
+from repro.obs.spans import span
 from repro.sched.engine import MultiHooks, SchedulerEngine
 from repro.sched.service import QuotaPrioritizer, wrap_tenancy
 from repro.sched.telemetry import RollingTelemetry, jain_index
@@ -455,13 +456,10 @@ class FederatedScheduler:
                 continue
             if stalled and (eng.done or eng.next_event_time() != math.inf):
                 continue   # only starved members get the override
-            if self.obs is None:
-                acted += len(scaler.control(eng, now, tel, stalled=stalled))
-                continue
-            t0 = time.perf_counter()
-            events = scaler.control(eng, now, tel, stalled=stalled)
-            self.obs.member(i).note_controller(
-                "autoscaler", len(events), time.perf_counter() - t0, now)
+            with span("autoscaler", sim_t=now, member=i):
+                events = scaler.control(eng, now, tel, stalled=stalled)
+            if self.obs is not None:
+                self.obs.member(i).note_controller("autoscaler", len(events))
             acted += len(events)
         return acted
 
@@ -663,7 +661,8 @@ def run_fleet(
 
     ``obs`` attaches a fleet-level ``repro.obs.Observability``: each member
     engine gets its own child tracer/metrics/audit hooks (distinct trace
-    pids), control-plane ticks are timed, and the bundle is finalized
+    pids), the program's spans go to its control-plane trace for the
+    run, and the bundle is finalized
     before the result is returned.  ``obs=None`` keeps the run bit-identical
     to an unobserved fleet.
 
@@ -700,66 +699,64 @@ def run_fleet(
         parallel=parallel, predictors=predictors)
 
     def _chaos_tick(now):
-        if obs is None:
-            return chaos.control(fed, now)
-        t0_w = time.perf_counter()
-        applied = chaos.control(fed, now)
-        obs.note_controller("fleet-chaos", len(applied),
-                            time.perf_counter() - t0_w, now)
+        with span("fleet-chaos", sim_t=now):
+            applied = chaos.control(fed, now)
+        if obs is not None:
+            obs.note_controller("fleet-chaos", len(applied))
         return applied
 
-    jobs = sorted((j.clone_pending() for j in run.jobs),
-                  key=lambda j: j.submit_time)
-    iv = max(rescan_interval, 1e-6)
-    t0 = jobs[0].submit_time if jobs else 0.0
-    t = t0
-    feed = 0
-    windows = 0
-    while True:
-        hi = feed
-        while hi < len(jobs) and jobs[hi].submit_time <= t + iv:
-            hi += 1
-        if hi > feed:
-            fed.submit(jobs[feed:hi])
-            feed = hi
-        if feed >= len(jobs) and (fed.done
-                                  or fed.next_event_time() == math.inf):
-            if not fed.done and chaos is not None \
-                    and chaos.next_time() < math.inf:
-                # dry heaps with work still queued (or parked routes): only
-                # a chaos event — e.g. the restore ending a blackout — can
-                # unblock them; hop to its window edge and tick
-                t = t0 + math.ceil((chaos.next_time() - t0) / iv) * iv
-                fed.step(t)
-                _chaos_tick(t)
+    with (obs.recording() if obs is not None
+          else contextlib.nullcontext()):
+        jobs = sorted((j.clone_pending() for j in run.jobs),
+                      key=lambda j: j.submit_time)
+        iv = max(rescan_interval, 1e-6)
+        t0 = jobs[0].submit_time if jobs else 0.0
+        t = t0
+        feed = 0
+        windows = 0
+        while True:
+            hi = feed
+            while hi < len(jobs) and jobs[hi].submit_time <= t + iv:
+                hi += 1
+            if hi > feed:
+                with span("service.submit", jobs=hi - feed):
+                    fed.submit(jobs[feed:hi])
+                feed = hi
+            if feed >= len(jobs) and (fed.done
+                                      or fed.next_event_time() == math.inf):
+                if not fed.done and chaos is not None \
+                        and chaos.next_time() < math.inf:
+                    # dry heaps with work still queued (or parked routes): only
+                    # a chaos event — e.g. the restore ending a blackout — can
+                    # unblock them; hop to its window edge and tick
+                    t = t0 + math.ceil((chaos.next_time() - t0) / iv) * iv
+                    fed.step(t)
+                    _chaos_tick(t)
+                    continue
+                if fed.done or autoscalers is None:
+                    break
+                # starved member(s) with dry heaps: only added capacity can
+                # unblock them (same stall override as service.run_stream)
+                t += iv
+                if not fed.control_stalled(t) \
+                        and fed.next_event_time() == math.inf:
+                    break
                 continue
-            if fed.done or autoscalers is None:
-                break
-            # starved member(s) with dry heaps: only added capacity can
-            # unblock them (same stall override as service.run_stream)
+            nxt = fed.next_event_time()
+            if feed < len(jobs):
+                nxt = min(nxt, jobs[feed].submit_time)
+            if chaos is not None:
+                nxt = min(nxt, chaos.next_time())
+            if nxt > t + iv:
+                t = t0 + math.floor((nxt - t0) / iv) * iv
+                continue
+            fed.step(t + iv)
+            if obs is not None:
+                obs.note_window()
             t += iv
-            if not fed.control_stalled(t) \
-                    and fed.next_event_time() == math.inf:
-                break
-            continue
-        nxt = fed.next_event_time()
-        if feed < len(jobs):
-            nxt = min(nxt, jobs[feed].submit_time)
-        if chaos is not None:
-            nxt = min(nxt, chaos.next_time())
-        if nxt > t + iv:
-            t = t0 + math.floor((nxt - t0) / iv) * iv
-            continue
-        if obs is not None:
-            t_step = time.perf_counter()
-            fed.step(t + iv)
-            obs.note_window(t, time.perf_counter() - t_step, 0)
-        else:
-            fed.step(t + iv)
-        t += iv
-        windows += 1
-        if chaos is not None:
-            _chaos_tick(t)
+            windows += 1
+            if chaos is not None:
+                _chaos_tick(t)
     fed.finalize_telemetry()
     fed.close()
     if obs is not None:

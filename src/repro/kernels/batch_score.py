@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.obs.spans import span
+
 #: bucket ladder bounds: smallest bucket matches the actor window, largest
 #: caps compile count (and VMEM footprint) at 16k-deep benches
 MIN_BUCKET = 256
@@ -89,4 +91,8 @@ class BucketedScorer:
             mask[:m] = 1.0
             return self._score_bucket(x_pad, mask)
 
-        return run_bucketed(run, feats, hi=self.max_bucket)
+        n = len(feats)
+        with span("rank.scorer", rows=n,
+                  bucket=bucket_for(min(n, self.max_bucket),
+                                    hi=self.max_bucket)):
+            return run_bucketed(run, feats, hi=self.max_bucket)

@@ -10,6 +10,7 @@ scheduling decision on the optimized path:
     {"now": float,            # simulated decision instant
      "path": "policy" | "fcfs-degraded",     # who ranked the window
      "window": int,           # ranking-window size handed to the policy
+     "rank_start_ns": int,    # ranking's start, repro.obs.spans.clock_ns
      "rank_wall_s": float,    # wall-clock spent ranking
      "top_job": int,          # job id the policy put first
      "placed": bool,          # did the top job start this decision

@@ -10,16 +10,23 @@ format Perfetto and ``chrome://tracing`` load directly):
   events marking preemptions (resume penalty attached), fault requeues,
   and checkpoint resumes.  ``tid`` is the job id; ``ts`` is microseconds
   of simulated time since the first observed instant.
-- **Control plane** (wall-clock time, its own process track): per-decision
-  ``rank`` spans (policy vs FCFS-degraded path, from the engine's audit
-  stream), per-attempt ``alloc`` spans (MILP / greedy-fallback /
-  heuristic), and per-rescan-window autoscaler / preemption / chaos
-  controller ticks forwarded by the service loop.
+- **Control plane** (the profiler's host clock, its own process track):
+  per-decision ``rank`` spans (policy vs FCFS-degraded path, from the
+  engine's audit stream), per-placement ``alloc`` spans (MILP /
+  greedy-fallback / heuristic), and every program span
+  (``repro.obs.spans``: ``engine.decide``, ``rank.*``, ``backfill``,
+  controller ticks, ...) closed while the owning bundle records, one
+  track per thread, with the enclosing span's name in ``args.parent``.
+  ``ts`` is microseconds since ``otherData.clock_origin_ns`` on
+  :func:`repro.obs.spans.clock_ns`, the clock ``jax.profiler`` stamps
+  host events with, so a control-plane event and the same span in a
+  profile line up.
 
 The two timelines use different clocks, so they live in different trace
 ``pid``s — each is internally consistent, and control-plane events carry
-``sim_t`` in ``args`` for cross-referencing.  ``validate_trace`` checks
-the exported document against the trace-event schema (CI gates on it).
+``sim_t`` in ``args`` for cross-referencing where they have one.
+``validate_trace`` checks the exported document against the trace-event
+schema (CI gates on it).
 
 Jobs paused or migrated away (``pause_job`` / ``withdraw_pending`` fire no
 engine hooks by design) keep their last span open until a later hook or
@@ -30,13 +37,14 @@ opening on the destination's.
 from __future__ import annotations
 
 import json
-import time
+import threading
 
+from repro.obs.spans import ORIGIN_NS, clock_ns
 from repro.sched.engine import EngineHooks
 
 #: trace pid carrying simulated-time job spans (offset by member index).
 JOB_PID_BASE = 1
-#: trace pid carrying wall-clock control-plane spans.
+#: trace pid carrying control-plane spans on the profiler's host clock.
 CONTROL_PID_BASE = 1001
 
 _REQUIRED_KEYS = {"name", "ph", "ts", "pid", "tid"}
@@ -64,7 +72,6 @@ class SpanTracer(EngineHooks):
         self.events: list[dict] = []
         self.dropped = 0
         self._t0: float | None = None          # sim-time origin
-        self._wall0 = time.perf_counter()      # wall-time origin
         self._queued_since: dict[int, float] = {}
         self._running_since: dict[int, float] = {}
         self._preempting: set[int] = set()
@@ -75,7 +82,7 @@ class SpanTracer(EngineHooks):
     def _meta(self) -> None:
         for pid, label in ((self.job_pid, f"{self.name} jobs (sim time)"),
                            (self.ctrl_pid,
-                            f"{self.name} control plane (wall clock)")):
+                            f"{self.name} control plane (host clock)")):
             self.events.append({"name": "process_name", "ph": "M",
                                 "pid": pid, "tid": 0, "ts": 0,
                                 "args": {"name": label}})
@@ -91,8 +98,9 @@ class SpanTracer(EngineHooks):
             self._t0 = t
         return int(round((t - self._t0) * 1e6))
 
-    def _wall_us(self) -> int:
-        return int(round((time.perf_counter() - self._wall0) * 1e6))
+    @staticmethod
+    def _clock_us(t_ns: int) -> float:
+        return max(t_ns - ORIGIN_NS, 0) / 1e3
 
     def _job_span(self, name: str, jid: int, t_start: float, t_end: float,
                   **args) -> None:
@@ -106,17 +114,21 @@ class SpanTracer(EngineHooks):
                     "ts": self._sim_us(t), "pid": self.job_pid, "tid": jid,
                     "args": args})
 
-    def control_span(self, name: str, tid: str, wall_s: float,
+    def control_span(self, name: str, tid: str, start_ns: int, end_ns: int,
                      **args) -> None:
-        """Record a wall-clock control-plane span ending *now* (the service
-        loop and engine call this right after timing the work)."""
-        dur = max(int(round(wall_s * 1e6)), 0)
-        # clamp: a span timed before this tracer's wall origin (e.g. handed
-        # in from an older clock) must not produce a negative timestamp
-        ts = max(self._wall_us() - dur, 0)
+        """Record a control-plane span from ``start_ns`` to ``end_ns`` on
+        :func:`repro.obs.spans.clock_ns`."""
+        ts = self._clock_us(start_ns)
         self._emit({"name": name, "ph": "X", "cat": "control",
-                    "ts": ts, "dur": dur,
+                    "ts": ts, "dur": max(self._clock_us(end_ns) - ts, 0.0),
                     "pid": self.ctrl_pid, "tid": tid, "args": args})
+
+    def program_span(self, name: str, start_ns: int, end_ns: int,
+                     parent: str | None, meta: dict) -> None:
+        """Record one program span (``repro.obs.spans``) on the track of
+        the thread that closed it."""
+        self.control_span(name, threading.current_thread().name, start_ns,
+                          end_ns, parent=parent, **meta)
 
     # ----------------------------------------------------------- hook API ----
     def on_submit(self, job, now):
@@ -178,20 +190,26 @@ class SpanTracer(EngineHooks):
 
     # -- engine audit stream (gated: only fires when a hook defines these) --
     def on_alloc(self, job, placement, now, wall_s, path):
-        self.control_span(f"alloc:{path}", "alloc", wall_s, sim_t=now,
+        # the engine fires this as the placement's timing ends
+        end = clock_ns()
+        self.control_span(f"alloc:{path}", "alloc",
+                          end - int(round(wall_s * 1e9)), end, sim_t=now,
                           job=job.job_id, placed=placement is not None,
                           gpus=job.num_gpus)
 
     def on_decision_audit(self, rec):
-        self.control_span(f"rank:{rec['path']}", "rank",
-                          rec.get("rank_wall_s", 0.0), sim_t=rec["now"],
-                          window=rec["window"], top_job=rec["top_job"],
-                          placed=rec["placed"], skips=rec.get("skips", {}))
+        start = rec["rank_start_ns"]
+        self.control_span(f"rank:{rec['path']}", "rank", start,
+                          start + int(round(rec["rank_wall_s"] * 1e9)),
+                          sim_t=rec["now"], window=rec["window"],
+                          top_job=rec["top_job"], placed=rec["placed"],
+                          skips=rec.get("skips", {}))
 
     def on_window_blocked(self, now, queued):
         self._emit({"name": "window-blocked", "ph": "i", "cat": "control",
-                    "s": "p", "ts": self._wall_us(), "pid": self.ctrl_pid,
-                    "tid": "rank", "args": {"sim_t": now, "queued": queued}})
+                    "s": "p", "ts": self._clock_us(clock_ns()),
+                    "pid": self.ctrl_pid, "tid": "rank",
+                    "args": {"sim_t": now, "queued": queued}})
 
     # ----------------------------------------------------------- finalize ----
     def finalize(self, now: float | None = None) -> None:
@@ -220,6 +238,9 @@ class SpanTracer(EngineHooks):
                 "displayTimeUnit": "ms",
                 "otherData": {"tracer": self.name,
                               "dropped_events": self.dropped,
+                              # control-plane ts are microseconds since
+                              # this instant of the profiler's host clock
+                              "clock_origin_ns": ORIGIN_NS,
                               # sim-time origin per job pid: report tooling
                               # maps span ts back to absolute sim seconds
                               "sim_t0": {str(self.job_pid):
@@ -245,7 +266,7 @@ def merge_documents(docs) -> dict:
         t0s.update(other.get("sim_t0", {}))
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"tracer": "fleet", "dropped_events": dropped,
-                          "sim_t0": t0s}}
+                          "clock_origin_ns": ORIGIN_NS, "sim_t0": t0s}}
 
 
 def validate_trace(doc) -> list[str]:

@@ -44,6 +44,7 @@ from collections import deque
 import numpy as np
 
 from repro.core.features import NUM_FEATURES, build_features
+from repro.obs.spans import span
 from repro.sched.engine import EngineHooks
 from repro.core.types import Job
 
@@ -376,45 +377,47 @@ class RuntimePredictor(EngineHooks):
 
     # ------------------------------------------------------------- training --
     def on_submit(self, job: Job, now: float) -> None:
-        if len(self._cache) >= self.max_cached:
-            self._cache.pop(next(iter(self._cache)))
-        self._cache[job.job_id] = self._job_row(job, self.engine, now)
+        with span("predict.submit"):
+            if len(self._cache) >= self.max_cached:
+                self._cache.pop(next(iter(self._cache)))
+            self._cache[job.job_id] = self._job_row(job, self.engine, now)
 
     def on_finish(self, job: Job, now: float) -> None:
-        actual = max(float(job.runtime), 1.0)
-        anchor = self._anchor(job)
-        row = self._cache.pop(job.job_id, None)
-        if row is None:
-            row = self._job_row(job, self.engine, now)
-        x = np.empty(PREDICT_FEATURES, np.float32)
-        x[:NUM_FEATURES] = row
-        x[NUM_FEATURES:] = (self._context(self.engine)
-                            if self.engine is not None else self._ctx)
-        # prequential errors: predict with the *current* model, then update
-        r = float(np.clip(self.mlp.forward(x[None, :])[0, 0],
-                          -RESID_CLAMP, RESID_CLAMP))
-        p50 = max(anchor * math.exp(r), 1.0)
-        base = max(self.baseline.predict(job), 1.0)
-        e_mlp = abs(p50 - actual) / actual
-        e_base = abs(base - actual) / actual
-        self._err_mlp.append(e_mlp)
-        self._err_base.append(e_base)
-        self._sum_err_mlp += e_mlp
-        self._sum_err_base += e_base
-        self._n_err += 1
-        y = min(max(math.log(actual / anchor), -RESID_CLAMP), RESID_CLAMP)
-        self.mlp.sgd_step(x, y)
-        est = float(job.est_runtime)
-        if math.isfinite(est) and est > 0.0:
-            # cohort bias is measured against the *declared* estimate (the
-            # debiased anchor would feed back on itself)
-            yb = min(max(math.log(actual / max(est, 1.0)),
-                         -RESID_CLAMP), RESID_CLAMP)
-            key = (job.user, _gpu_bucket(job.num_gpus))
-            self._bias_sum[key] = self._bias_sum.get(key, 0.0) + yb
-            self._bias_n[key] = self._bias_n.get(key, 0) + 1
-        self.baseline.observe(job, actual)
-        self.train_steps += 1
+        with span("predict.train"):
+            actual = max(float(job.runtime), 1.0)
+            anchor = self._anchor(job)
+            row = self._cache.pop(job.job_id, None)
+            if row is None:
+                row = self._job_row(job, self.engine, now)
+            x = np.empty(PREDICT_FEATURES, np.float32)
+            x[:NUM_FEATURES] = row
+            x[NUM_FEATURES:] = (self._context(self.engine)
+                                if self.engine is not None else self._ctx)
+            # prequential errors: predict with the *current* model, then update
+            r = float(np.clip(self.mlp.forward(x[None, :])[0, 0],
+                              -RESID_CLAMP, RESID_CLAMP))
+            p50 = max(anchor * math.exp(r), 1.0)
+            base = max(self.baseline.predict(job), 1.0)
+            e_mlp = abs(p50 - actual) / actual
+            e_base = abs(base - actual) / actual
+            self._err_mlp.append(e_mlp)
+            self._err_base.append(e_base)
+            self._sum_err_mlp += e_mlp
+            self._sum_err_base += e_base
+            self._n_err += 1
+            y = min(max(math.log(actual / anchor), -RESID_CLAMP), RESID_CLAMP)
+            self.mlp.sgd_step(x, y)
+            est = float(job.est_runtime)
+            if math.isfinite(est) and est > 0.0:
+                # cohort bias is measured against the *declared* estimate (the
+                # debiased anchor would feed back on itself)
+                yb = min(max(math.log(actual / max(est, 1.0)),
+                             -RESID_CLAMP), RESID_CLAMP)
+                key = (job.user, _gpu_bucket(job.num_gpus))
+                self._bias_sum[key] = self._bias_sum.get(key, 0.0) + yb
+                self._bias_n[key] = self._bias_n.get(key, 0) + 1
+            self.baseline.observe(job, actual)
+            self.train_steps += 1
 
     # ------------------------------------------------------------ reporting --
     def note_reservation(self, slack_s: float) -> None:

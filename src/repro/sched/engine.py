@@ -73,6 +73,7 @@ from repro.core.prioritizer import (  # noqa: F401  (PolicyPrioritizer
     Prioritizer, WindowFields)
 from repro.core.types import ClusterSpec, Job, JobState
 from repro.lifecycle.machine import transition
+from repro.obs.spans import clock_ns, span
 
 #: Pending-queue window handed to the prioritizer each decision (the seed
 #: hard-coded ``10 * 256``; now a configurable engine parameter).
@@ -689,10 +690,12 @@ class SchedulerEngine:
                     jid = p
                     rec = self.running.get(jid)
                     if rec is not None and abs(rec[3] - now) < 1e-6:
-                        self._finish_job(jid)
+                        with span("engine.finish"):
+                            self._finish_job(jid)
             self._try_schedule()
-            for h in self.hooks:
-                h.on_tick(self.now, self)
+            with span("engine.hooks"):
+                for h in self.hooks:
+                    h.on_tick(self.now, self)
             processed += 1
         return processed
 
@@ -885,11 +888,11 @@ class SchedulerEngine:
         if not obs:
             return self._alloc_impl(job, queue_rest, durations)
         calls0, fb0 = self.milp_calls, self.milp_fallbacks
-        t0 = time.perf_counter()
+        t0 = clock_ns()
         placement = self._alloc_impl(job, queue_rest, durations)
         if placement is None:
             return None
-        wall = time.perf_counter() - t0
+        wall = (clock_ns() - t0) * 1e-9
         if self.milp_fallbacks > fb0:
             path = "greedy-fallback"
         elif self.milp_calls > calls0:
@@ -1483,7 +1486,8 @@ class SchedulerEngine:
         cluster, prioritizer = self.cluster, self.prioritizer
         rank_window = self._rank_window
         #: with audit observers attached (repro.obs) every decision builds
-        #: one record — rank path, wall-clock, allocator path, skip-reason
+        #: one record — rank path, start and wall-clock on the profiler's
+        #: clock (repro.obs.spans.clock_ns), allocator path, skip-reason
         #: tallies — delivered via one on_decision_audit call; with none
         #: (`audit` empty, the default) no clock is read and no dict is
         #: built, keeping the pass bit-identical to the pre-obs engine
@@ -1500,158 +1504,167 @@ class SchedulerEngine:
                         if fn is not None:
                             fn(self.now, queued)
                 return
-            # pending is maintained sorted by (submit_time, job_id): window
-            # extraction is a slice, no re-sort
-            queue = self.pending[: self.queue_window]
-            t_rank = time.perf_counter() if audit else 0.0
-            fcfs = self._fcfs_degraded()
-            if fcfs:
-                order = list(range(len(queue)))
-            elif rank_window is not None:
-                order = rank_window(queue, cluster, self.now,
-                                    self._pindex.window(self.queue_window))
-            else:
-                order = prioritizer.rank(queue, cluster, self.now)
-            self.decisions += 1
-            if self.hooks:
-                self._fire_decision(queue, order)
-            top = queue[order[0]]
-            rec = None
-            if audit:
-                rec = {"now": self.now,
-                       "path": "fcfs-degraded" if fcfs else "policy",
-                       "window": len(queue),
-                       "rank_wall_s": time.perf_counter() - t_rank,
-                       "top_job": top.job_id, "placed": False,
-                       "alloc": "none", "skips": {}, "backfills": 0}
-            k_look = self.lookahead_k
-            if (self.deep_lookahead_k is not None
-                    and len(self.pending) > self.deep_queue_threshold):
-                k_look = min(k_look, self.deep_lookahead_k)
-            rest = [queue[i] for i in order[1:1 + k_look]]
-            durations = self._lookahead_durations(rest)
-            calls0, fb0 = self.milp_calls, self.milp_fallbacks
-            placement = self._alloc_for(top, rest, durations)
-            if placement is not None:
-                if rec is not None:
-                    rec["placed"] = True
-                    rec["alloc"] = ("greedy-fallback"
-                                    if self.milp_fallbacks > fb0
-                                    else "milp"
-                                    if self.milp_calls > calls0
-                                    else "heuristic")
-                    self._fire_audit(rec)
-                self._remove_pending(top)
-                self._start_job(top, placement)
-                continue
-            if rec is not None:
-                rec["skips"]["head-no-placement"] = 1
-            if not self.backfill:
-                if rec is not None:
-                    self._fire_audit(rec)
-                return
-            # EASY backfill under reservation for `top`.  The audit skip
-            # tallies use local ints folded into the record after the loop:
-            # a deep window makes O(queue_window) skips per decision, and
-            # per-skip dict updates would show up in the decision latency
-            # the audit record itself reports.  Candidate placements go
-            # straight to ``_alloc_impl`` for the same reason (identical to
-            # ``_alloc_for`` when no observers are attached) — alloc spans
-            # cover head-of-queue placements; backfill starts are counted
-            # in the record's ``backfills`` field.
-            t_res = self._earliest_start(top)
-            progressed = False
-            # Vectorized candidate filter over the pending-index columns.
-            # The pindex still mirrors `queue` row-for-row (nothing was
-            # removed since the slice — the head alloc just failed), so the
-            # scalar reference's per-candidate test
-            # ``now + max(rt, 1.0) > t_res`` is evaluated for the whole
-            # window in one float64 expression with identical operations.
-            # Every entry of order[1:] is a distinct PENDING job != top at
-            # this instant (pending holds only PENDING jobs and order is a
-            # permutation), so tallying overruns off the raw mask matches
-            # the scalar loop's count exactly.
-            pindex = self._pindex
-            w = len(queue)
-            pred = self._predict_assist()
-            if pred is not None:
-                # prediction-assisted gate: a candidate backfills only if
-                # its predicted p90 runtime fits before the reservation —
-                # conservative quantile in place of the declared runtime.
-                # Jobs that already blew a reservation are barred.
-                p90 = np.maximum(pred.reserve_batch(queue, self), 1.0)
-                time_ok = self.now + p90 <= t_res
-                barred = self._bf_overrun_jobs
-                if barred:
-                    for k, cj in enumerate(queue):
-                        if cj.job_id in barred:
-                            time_ok[k] = False
-            else:
-                rt_col = pindex._est if prioritizer.use_estimates \
-                    else pindex._rt
-                time_ok = self.now + np.maximum(rt_col[:w], 1.0) <= t_res
-            sid_snap = pindex._sid[:w].copy()   # survives removals below
-            order_arr = np.asarray(order[1:], dtype=np.intp)
-            ok = time_ok[order_arr]
-            sk_over = int(ok.size) - int(ok.sum())
-            neg = self._neg_shapes
-            if cluster.version != self._neg_ver:
-                self._neg_ver = cluster.version
-                neg.clear()
-            free_any, free_by_type = cluster.free_gpu_tallies()
-            sk_nopl = 0
-            for i in order_arr[ok]:
-                cand = queue[i]
-                if cand.state != JobState.PENDING or cand is top:
-                    continue   # unreachable by the invariant above; kept
-                sid = sid_snap[i]
-                if sid in neg:
-                    # shape already proven unplaceable at this cluster
-                    # version — same None `_alloc_impl` would return
-                    sk_nopl += 1
-                    continue
-                # free-tally prefilter: a per-SKU shortfall is a proof of
-                # infeasibility (the same necessary condition
-                # `_any_schedulable` uses), so `_alloc_impl` would return
-                # None — skip the candidate-ways probe entirely
-                avail = free_any if cand.gpu_type == "any" \
-                    else free_by_type.get(cand.gpu_type, 0)
-                if avail < cand.num_gpus:
-                    neg.add(sid)
-                    sk_nopl += 1
-                    continue
-                pl = self._alloc_impl(cand, [])
-                if pl is not None:
-                    self._remove_pending(cand)
-                    self._start_job(cand, pl)
-                    self.backfills += 1
-                    progressed = True
-                    if pred is not None and t_res < math.inf:
-                        self.bf_reservations += 1
-                        self._bf_deadlines[cand.job_id] = t_res
-                        note = getattr(pred, "note_reservation", None)
-                        if note is not None:
-                            note(t_res - (self.now + float(p90[i])))
-                    if rec is not None:
-                        rec["backfills"] += 1
-                    # the allocation bumped cluster.version: start fresh
-                    self._neg_ver = cluster.version
-                    neg.clear()
-                    free_any, free_by_type = cluster.free_gpu_tallies()
+            # one span per decision, parent of ranking, placement and
+            # backfill; its id is the engine's decision counter
+            with span("engine.decide", decision=self.decisions + 1,
+                      window=min(self.queue_window, len(self.pending))):
+                # pending is maintained sorted by (submit_time, job_id): window
+                # extraction is a slice, no re-sort
+                queue = self.pending[: self.queue_window]
+                t_rank = clock_ns() if audit else 0
+                fcfs = self._fcfs_degraded()
+                if fcfs:
+                    order = list(range(len(queue)))
+                elif rank_window is not None:
+                    order = rank_window(queue, cluster, self.now,
+                                        self._pindex.window(self.queue_window))
                 else:
-                    neg.add(sid)
-                    sk_nopl += 1
-            if rec is not None:
-                if sk_over:
-                    rec["skips"]["backfill-overrun"] = sk_over
-                if sk_nopl:
-                    rec["skips"]["backfill-no-placement"] = sk_nopl
-                self._fire_audit(rec)
-            if not progressed:
-                return
-            # after backfills the reserved job may now fit; loop again
-            if not cluster.can_schedule_now(top):
-                return
+                    order = prioritizer.rank(queue, cluster, self.now)
+                self.decisions += 1
+                if self.hooks:
+                    self._fire_decision(queue, order)
+                top = queue[order[0]]
+                rec = None
+                if audit:
+                    rec = {"now": self.now,
+                           "path": "fcfs-degraded" if fcfs else "policy",
+                           "window": len(queue),
+                           "rank_start_ns": t_rank,
+                           "rank_wall_s": (clock_ns() - t_rank) * 1e-9,
+                           "top_job": top.job_id, "placed": False,
+                           "alloc": "none", "skips": {}, "backfills": 0}
+                k_look = self.lookahead_k
+                if (self.deep_lookahead_k is not None
+                        and len(self.pending) > self.deep_queue_threshold):
+                    k_look = min(k_look, self.deep_lookahead_k)
+                rest = [queue[i] for i in order[1:1 + k_look]]
+                durations = self._lookahead_durations(rest)
+                calls0, fb0 = self.milp_calls, self.milp_fallbacks
+                placement = self._alloc_for(top, rest, durations)
+                if placement is not None:
+                    if rec is not None:
+                        rec["placed"] = True
+                        rec["alloc"] = ("greedy-fallback"
+                                        if self.milp_fallbacks > fb0
+                                        else "milp"
+                                        if self.milp_calls > calls0
+                                        else "heuristic")
+                        self._fire_audit(rec)
+                    self._remove_pending(top)
+                    self._start_job(top, placement)
+                    continue
+                if rec is not None:
+                    rec["skips"]["head-no-placement"] = 1
+                if not self.backfill:
+                    if rec is not None:
+                        self._fire_audit(rec)
+                    return
+                # EASY backfill under reservation for `top`.  The audit skip
+                # tallies use local ints folded into the record after the loop:
+                # a deep window makes O(queue_window) skips per decision, and
+                # per-skip dict updates would show up in the decision latency
+                # the audit record itself reports.  Candidate placements go
+                # straight to ``_alloc_impl`` for the same reason (identical to
+                # ``_alloc_for`` when no observers are attached) — alloc spans
+                # cover head-of-queue placements; backfill starts are counted
+                # in the record's ``backfills`` field.
+                with span("backfill", decision=self.decisions) as bf:
+                    bf0 = self.backfills
+                    t_res = self._earliest_start(top)
+                    progressed = False
+                    # Vectorized candidate filter over the pending-index columns.
+                    # The pindex still mirrors `queue` row-for-row (nothing was
+                    # removed since the slice — the head alloc just failed), so the
+                    # scalar reference's per-candidate test
+                    # ``now + max(rt, 1.0) > t_res`` is evaluated for the whole
+                    # window in one float64 expression with identical operations.
+                    # Every entry of order[1:] is a distinct PENDING job != top at
+                    # this instant (pending holds only PENDING jobs and order is a
+                    # permutation), so tallying overruns off the raw mask matches
+                    # the scalar loop's count exactly.
+                    pindex = self._pindex
+                    w = len(queue)
+                    pred = self._predict_assist()
+                    if pred is not None:
+                        # prediction-assisted gate: a candidate backfills only if
+                        # its predicted p90 runtime fits before the reservation —
+                        # conservative quantile in place of the declared runtime.
+                        # Jobs that already blew a reservation are barred.
+                        p90 = np.maximum(pred.reserve_batch(queue, self), 1.0)
+                        time_ok = self.now + p90 <= t_res
+                        barred = self._bf_overrun_jobs
+                        if barred:
+                            for k, cj in enumerate(queue):
+                                if cj.job_id in barred:
+                                    time_ok[k] = False
+                    else:
+                        rt_col = pindex._est if prioritizer.use_estimates \
+                            else pindex._rt
+                        time_ok = self.now + np.maximum(rt_col[:w], 1.0) <= t_res
+                    sid_snap = pindex._sid[:w].copy()   # survives removals below
+                    order_arr = np.asarray(order[1:], dtype=np.intp)
+                    ok = time_ok[order_arr]
+                    sk_over = int(ok.size) - int(ok.sum())
+                    neg = self._neg_shapes
+                    if cluster.version != self._neg_ver:
+                        self._neg_ver = cluster.version
+                        neg.clear()
+                    free_any, free_by_type = cluster.free_gpu_tallies()
+                    sk_nopl = 0
+                    for i in order_arr[ok]:
+                        cand = queue[i]
+                        if cand.state != JobState.PENDING or cand is top:
+                            continue   # unreachable by the invariant above; kept
+                        sid = sid_snap[i]
+                        if sid in neg:
+                            # shape already proven unplaceable at this cluster
+                            # version — same None `_alloc_impl` would return
+                            sk_nopl += 1
+                            continue
+                        # free-tally prefilter: a per-SKU shortfall is a proof of
+                        # infeasibility (the same necessary condition
+                        # `_any_schedulable` uses), so `_alloc_impl` would return
+                        # None — skip the candidate-ways probe entirely
+                        avail = free_any if cand.gpu_type == "any" \
+                            else free_by_type.get(cand.gpu_type, 0)
+                        if avail < cand.num_gpus:
+                            neg.add(sid)
+                            sk_nopl += 1
+                            continue
+                        pl = self._alloc_impl(cand, [])
+                        if pl is not None:
+                            self._remove_pending(cand)
+                            self._start_job(cand, pl)
+                            self.backfills += 1
+                            progressed = True
+                            if pred is not None and t_res < math.inf:
+                                self.bf_reservations += 1
+                                self._bf_deadlines[cand.job_id] = t_res
+                                note = getattr(pred, "note_reservation", None)
+                                if note is not None:
+                                    note(t_res - (self.now + float(p90[i])))
+                            if rec is not None:
+                                rec["backfills"] += 1
+                            # the allocation bumped cluster.version: start fresh
+                            self._neg_ver = cluster.version
+                            neg.clear()
+                            free_any, free_by_type = cluster.free_gpu_tallies()
+                        else:
+                            neg.add(sid)
+                            sk_nopl += 1
+                    bf.set(tried=int(ok.size) - sk_over,
+                           started=self.backfills - bf0)
+                if rec is not None:
+                    if sk_over:
+                        rec["skips"]["backfill-overrun"] = sk_over
+                    if sk_nopl:
+                        rec["skips"]["backfill-no-placement"] = sk_nopl
+                    self._fire_audit(rec)
+                if not progressed:
+                    return
+                # after backfills the reserved job may now fit; loop again
+                if not cluster.can_schedule_now(top):
+                    return
 
     # ------------------------------------------------------------ failover ----
     #: everything a restored engine needs to resume bit-identically.  Hooks

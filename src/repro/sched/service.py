@@ -15,15 +15,16 @@ autoscalers, or checkpoint the service.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
-import time
 from typing import Callable
 
 from repro.core.faults import FaultModel
 from repro.core.metrics import BatchResult
 from repro.core.policies import make_policy
 from repro.core.types import ClusterSpec, Job
+from repro.obs.spans import span
 from repro.sched.engine import (DEFAULT_QUEUE_WINDOW, EngineHooks,
                                 MultiHooks, PolicyPrioritizer, Prioritizer,
                                 SchedulerEngine)
@@ -43,18 +44,16 @@ class StreamResult:
 
 
 def _controller_tick(obs, kind: str, now: float, fn):
-    """Run one controller tick; with an ``Observability`` bundle armed,
-    wall-clock the tick and record it as a control-plane span plus
-    tick/action counters.  ``obs=None`` calls ``fn`` directly."""
-    if obs is None:
-        return fn()
-    t0 = time.perf_counter()
-    events = fn()
-    try:
-        n = len(events)
-    except TypeError:
-        n = int(bool(events))
-    obs.note_controller(kind, n, time.perf_counter() - t0, now)
+    """Run one controller tick as a span named ``kind``; with an
+    ``Observability`` bundle armed, count the tick and its actions."""
+    with span(kind, sim_t=now):
+        events = fn()
+    if obs is not None:
+        try:
+            n = len(events)
+        except TypeError:
+            n = int(bool(events))
+        obs.note_controller(kind, n)
     return events
 
 
@@ -251,8 +250,10 @@ def run_stream(
     ``None``: bit-identical to the pre-chaos service (pinned by tests).
 
     ``obs`` (a ``repro.obs.Observability``) attaches the tracing / metrics /
-    audit sinks and wall-clocks every controller tick into the control-plane
-    trace.  ``obs=None`` leaves the schedule bit-identical (pinned).
+    audit sinks and records the program's spans (``repro.obs.spans``:
+    each window's arrivals, every decision and its layers, the controller
+    ticks) into its control-plane trace for the run.  ``obs=None`` leaves
+    the schedule bit-identical (pinned).
 
     ``predictor`` (a ``repro.predict.RuntimePredictor``) trains online from
     completion hooks and — when ``assist=True`` — gates EASY backfill on
@@ -294,82 +295,85 @@ def run_stream(
     if isinstance(prioritizer, QuotaPrioritizer):
         prioritizer.engine = engine
 
-    jobs = sorted(jobs, key=lambda j: j.submit_time)
-    feed = 0
-    if not chunked_submit:
-        engine.submit(jobs)
-        feed = len(jobs)
+    with (obs.recording() if obs is not None
+          else contextlib.nullcontext()):
+        jobs = sorted(jobs, key=lambda j: j.submit_time)
+        feed = 0
+        if not chunked_submit:
+            with span("service.submit", jobs=len(jobs)):
+                engine.submit(jobs)
+            feed = len(jobs)
 
-    iv = max(rescan_interval, 1e-6)
-    t0 = jobs[0].submit_time if jobs else 0.0
-    t = t0
-    windows = 0
-    while True:
-        # feed the arrivals due in the upcoming window
-        hi = feed
-        while hi < len(jobs) and jobs[hi].submit_time <= t + iv:
-            hi += 1
-        if hi > feed:
-            engine.submit(jobs[feed:hi])
-            feed = hi
-        if feed >= len(jobs) and (engine.done
-                                  or engine.next_event_time() == math.inf):
-            if not engine.done and chaos is not None \
-                    and chaos.next_time() < math.inf:
-                # dry heap with queued jobs: only a chaos event (e.g. the
-                # recover closing a burst that took the last capable nodes)
-                # can unblock them — hop to its window edge and tick
-                t = t0 + math.ceil((chaos.next_time() - t0) / iv) * iv
-                engine.step(t)
-                _controller_tick(obs, "chaos", t,
-                                 lambda t=t: chaos.control(engine, t,
-                                                           telemetry))
+        iv = max(rescan_interval, 1e-6)
+        t0 = jobs[0].submit_time if jobs else 0.0
+        t = t0
+        windows = 0
+        while True:
+            # feed the arrivals due in the upcoming window
+            hi = feed
+            while hi < len(jobs) and jobs[hi].submit_time <= t + iv:
+                hi += 1
+            if hi > feed:
+                with span("service.submit", jobs=hi - feed):
+                    engine.submit(jobs[feed:hi])
+                feed = hi
+            if feed >= len(jobs) and (engine.done
+                                      or engine.next_event_time() == math.inf):
+                if not engine.done and chaos is not None \
+                        and chaos.next_time() < math.inf:
+                    # dry heap with queued jobs: only a chaos event (e.g. the
+                    # recover closing a burst that took the last capable nodes)
+                    # can unblock them — hop to its window edge and tick
+                    t = t0 + math.ceil((chaos.next_time() - t0) / iv) * iv
+                    engine.step(t)
+                    _controller_tick(obs, "chaos", t,
+                                     lambda t=t: chaos.control(engine, t,
+                                                               telemetry))
+                    continue
+                if engine.done or autoscaler is None:
+                    break
+                # starved queue with a dry heap: jobs are pending but no event
+                # can ever schedule them — only added capacity can.  Force a
+                # stall-override control tick; if the controller cannot act
+                # (every pool at its max bound) the job is genuinely
+                # unplaceable and the stream ends incomplete.
+                t += iv
+                acted = _controller_tick(
+                    obs, "autoscaler", t,
+                    lambda t=t: autoscaler.control(engine, t, telemetry,
+                                                   stalled=True))
+                if not acted and engine.next_event_time() == math.inf:
+                    break
                 continue
-            if engine.done or autoscaler is None:
-                break
-            # starved queue with a dry heap: jobs are pending but no event
-            # can ever schedule them — only added capacity can.  Force a
-            # stall-override control tick; if the controller cannot act
-            # (every pool at its max bound) the job is genuinely
-            # unplaceable and the stream ends incomplete.
+            nxt = engine.next_event_time()
+            if feed < len(jobs):
+                nxt = min(nxt, jobs[feed].submit_time)
+            if chaos is not None:
+                nxt = min(nxt, chaos.next_time())
+            if nxt > t + iv:
+                # nothing due for a while: hop empty windows in one grid-aligned
+                # jump, then re-run the feed so arrivals due in the hopped-to
+                # window are submitted before any queued event beyond them runs
+                t = t0 + math.floor((nxt - t0) / iv) * iv
+                continue
+            engine.step(t + iv)
             t += iv
-            acted = _controller_tick(
-                obs, "autoscaler", t,
-                lambda t=t: autoscaler.control(engine, t, telemetry,
-                                               stalled=True))
-            if not acted and engine.next_event_time() == math.inf:
-                break
-            continue
-        nxt = engine.next_event_time()
-        if feed < len(jobs):
-            nxt = min(nxt, jobs[feed].submit_time)
-        if chaos is not None:
-            nxt = min(nxt, chaos.next_time())
-        if nxt > t + iv:
-            # nothing due for a while: hop empty windows in one grid-aligned
-            # jump, then re-run the feed so arrivals due in the hopped-to
-            # window are submitted before any queued event beyond them runs
-            t = t0 + math.floor((nxt - t0) / iv) * iv
-            continue
-        t_step = time.perf_counter() if obs is not None else 0.0
-        processed = engine.step(t + iv)
-        t += iv
-        windows += 1
-        if obs is not None:
-            obs.note_window(t, time.perf_counter() - t_step, processed)
-        if chaos is not None:
-            _controller_tick(obs, "chaos", t,
-                             lambda t=t: chaos.control(engine, t, telemetry))
-        if autoscaler is not None:
-            _controller_tick(obs, "autoscaler", t,
-                             lambda t=t: autoscaler.control(engine, t,
-                                                            telemetry))
-        if preemption is not None:
-            _controller_tick(obs, "preemption", t,
-                             lambda t=t: preemption.control(engine, t,
-                                                            telemetry))
-        if on_window is not None:
-            on_window(engine, t, windows)
+            windows += 1
+            if obs is not None:
+                obs.note_window()
+            if chaos is not None:
+                _controller_tick(obs, "chaos", t,
+                                 lambda t=t: chaos.control(engine, t, telemetry))
+            if autoscaler is not None:
+                _controller_tick(obs, "autoscaler", t,
+                                 lambda t=t: autoscaler.control(engine, t,
+                                                                telemetry))
+            if preemption is not None:
+                _controller_tick(obs, "preemption", t,
+                                 lambda t=t: preemption.control(engine, t,
+                                                                telemetry))
+            if on_window is not None:
+                on_window(engine, t, windows)
     if telemetry is not None:
         telemetry.final(engine)
     if obs is not None:

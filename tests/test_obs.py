@@ -8,7 +8,7 @@ import pytest
 
 from repro.core import PolicyPrioritizer, make_policy
 from repro.obs import (DecisionAuditLog, EngineMetricsHook, MetricsRegistry,
-                       Observability, SpanTracer, merge_documents,
+                       Observability, SpanTracer, merge_documents, span,
                        validate_trace)
 from repro.obs.report import analyze, main as report_main, print_report
 from repro.sched import (EngineHooks, MultiHooks, SchedulerEngine,
@@ -551,8 +551,9 @@ def test_controller_ticks_recorded_in_metrics():
 
 def test_fleet_window_note_requires_no_nan():
     obs = Observability(name="f")
-    obs.note_window(0.0, 0.001, 3)
-    obs.note_controller("autoscaler", 2, 0.002, 60.0)
+    obs.note_window()
+    with obs.recording(), span("autoscaler", sim_t=60.0):
+        obs.note_controller("autoscaler", 2)
     assert validate_trace(obs.trace_document()) == []
     assert math.isfinite(
         obs.merged_registry().value("repro_rescan_windows_total"))

@@ -1,0 +1,222 @@
+"""Tests for the span facility ``repro.obs.spans``: the off path builds
+nothing and steers nothing, a ``jax.profiler`` session receives every
+span of the decision path with the right parent, and an attached bundle
+records the same spans on the profiler's clock."""
+import glob
+import os
+
+import jax
+import pytest
+
+from repro.core.agent import PPOAgent
+from repro.core.env import RLPrioritizer
+from repro.kernels.batch_score import BucketedScorer
+from repro.obs import Observability, spans, validate_trace
+from repro.obs.report import analyze
+from repro.predict import RuntimePredictor
+from repro.sched import get_scenario, run_stream
+
+#: span -> the program spans it may nest in directly (None: none of them)
+PARENTS = {
+    "service.submit": {None},
+    "predict.submit": {"service.submit"},
+    "engine.decide": {None},
+    "rank.features": {"engine.decide"},
+    "rank.scorer": {"engine.decide"},
+    "rank.actor": {"engine.decide"},
+    "rank.order": {"engine.decide"},
+    "milp.solve": {"engine.decide", "backfill"},
+    "backfill": {"engine.decide"},
+    "engine.finish": {None},
+    "predict.train": {"engine.finish"},
+    "engine.hooks": {None},
+}
+QUEUE_WINDOW = 320      # deeper than the actor's 256 slots: the scorer runs
+
+
+def _deep_stream(obs=None):
+    """An overcommitted queue over three SKUs (so the MILP runs, gangs
+    block the head and backfill starts jobs behind it) ranked by the
+    actor and, past 256 waiting jobs, the deep scorer; with a shadow
+    predictor."""
+    run = get_scenario("overcommit-queue").build(600, 0)
+    agent = PPOAgent()
+    pri = RLPrioritizer(agent, explore=False,
+                        deep_scorer=BucketedScorer(agent.params["actor"]))
+    res = run_stream(run.spec, [j.clone_pending() for j in run.jobs], pri,
+                     queue_window=QUEUE_WINDOW, chunked_submit=True,
+                     predictor=RuntimePredictor(assist=False), obs=obs)
+    eng = res.engine
+    return (tuple(sorted((j.job_id, j.first_start_time, j.finish_time)
+                         for j in eng.completed)),
+            (eng.decisions, eng.milp_calls, eng.backfills))
+
+
+@pytest.fixture(scope="module")
+def baseline():
+    return _deep_stream()
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """The deep stream under a CPU profiler session with a bundle
+    recording: its schedule, its bundle, and the profile's events."""
+    from jax.profiler import ProfileData
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    obs = Observability(name="t")
+    before = spans.traced_totals()
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        sig = _deep_stream(obs)
+    finally:
+        jax.profiler.stop_trace()
+    totals = {n: (c - before.get(n, (0, 0))[0], v - before.get(n, (0, 0))[1])
+              for n, (c, v) in spans.traced_totals().items()}
+    path = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                         "*.xplane.pb")))[-1]
+    pd = ProfileData.from_file(path)
+    events, start = [], None
+    for plane in pd.planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats)["profile_start_time"]
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in PARENTS:
+                    events.append((e.name, e.start_ns,
+                                   e.start_ns + e.duration_ns,
+                                   dict(e.stats)))
+    return {"sig": sig, "obs": obs, "events": events, "start_ns": start,
+            "totals": totals}
+
+
+def _parents(events):
+    """Innermost enclosing program span of every event (None: none)."""
+    out = []
+    for k, (name, s, e, meta) in enumerate(events):
+        best = None
+        for j, (pn, ps, pe, pm) in enumerate(events):
+            if j != k and ps <= s and e <= pe and (pe - ps) >= (e - s) \
+                    and (best is None or pe - ps < best[2] - best[1]):
+                best = (pn, ps, pe, pm)
+        out.append(best)
+    return out
+
+
+def test_off_opens_no_annotation_and_keeps_schedule(baseline, monkeypatch):
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **k):
+            made.append(a)
+            super().__init__(*a, **k)
+    monkeypatch.setattr(spans, "TraceAnnotation", Counting)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    assert spans.span("engine.decide", decision=1) is spans.span("x")
+    assert _deep_stream() == baseline
+    assert made == []
+
+
+def test_profiler_sees_every_span_with_its_parent(profiled, baseline):
+    assert profiled["sig"] == baseline
+    events = profiled["events"]
+    assert {n for n, *_ in events} == set(PARENTS)
+    for (name, s, e, meta), parent in zip(events, _parents(events)):
+        got = parent[0] if parent is not None else None
+        assert got in PARENTS[name], (name, got)
+
+
+def test_decision_id_is_shared_by_the_decisions_spans(profiled, baseline):
+    events = profiled["events"]
+    decides = [ev for ev in events if ev[0] == "engine.decide"]
+    assert [ev[3]["decision"] for ev in decides] \
+        == list(range(1, baseline[1][0] + 1))
+    assert all(ev[3]["window"] > 0 for ev in decides)
+    backfills = [(ev, p) for ev, p in zip(events, _parents(events))
+                 if ev[0] == "backfill"]
+    assert backfills
+    for (name, s, e, meta), parent in backfills:
+        assert meta["decision"] == parent[3]["decision"]
+        assert meta["tried"] >= meta["started"] >= 0
+    scored = [(ev[3], p[3]) for ev, p in zip(events, _parents(events))
+              if ev[0] == "rank.scorer"]
+    assert scored
+    for meta, decide in scored:
+        assert meta["rows"] == decide["window"] - 256 > 0
+        assert meta["bucket"] == 256
+
+
+def test_traced_totals_sum_the_profiles_events(profiled):
+    """What the process sums under the session is what the profile holds:
+    per span name the same calls, and the same seconds up to the few
+    microseconds of entering and leaving each TraceMe."""
+    totals = profiled["totals"]
+    assert {n for n, (c, _) in totals.items() if c} == set(PARENTS)
+    for name in PARENTS:
+        evs = [e - s for n, s, e, _ in profiled["events"] if n == name]
+        calls, secs = totals[name]
+        assert calls == len(evs), name
+        prof = sum(evs) * 1e-9
+        assert abs(secs - prof) <= 0.02 * prof + 20e-6 * calls, name
+
+
+def test_bundle_and_profile_share_the_clock(profiled):
+    """The same spans in the bundle's Chrome export and in the profile:
+    same count, start times within 1 ms on the profiler's host clock."""
+    doc = profiled["obs"].trace_document()
+    assert validate_trace(doc) == []
+    origin = doc["otherData"]["clock_origin_ns"]
+    chrome = [origin + ev["ts"] * 1e3 for ev in doc["traceEvents"]
+              if ev.get("name") == "engine.decide"]
+    prof = [profiled["start_ns"] + s for n, s, *_ in profiled["events"]
+            if n == "engine.decide"]
+    assert len(chrome) == len(prof) > 0
+    assert max(abs(a - b) for a, b in zip(chrome, prof)) < 1e6
+
+
+def test_bundle_counts_spans_and_report_still_reads_audit(profiled,
+                                                         baseline):
+    obs = profiled["obs"]
+    reg = obs.merged_registry()
+    decisions = baseline[1][0]
+    assert reg.value("repro_span_calls_total",
+                     span="engine.decide") == decisions
+    assert reg.value("repro_span_seconds_total", span="rank.actor") > 0
+    doc = obs.trace_document()
+    args = [ev["args"] for ev in doc["traceEvents"]
+            if ev.get("name") == "rank.features"]
+    assert args and all(a["parent"] == "engine.decide" for a in args)
+    model = analyze(doc)
+    assert sum(model["path_counts"].values()) == decisions
+    assert model["alloc_counts"]
+
+
+def test_recording_sink_gets_nesting_and_late_metadata():
+    got = []
+
+    class Sink:
+        def record_span(self, name, start_ns, end_ns, parent, meta):
+            got.append((name, end_ns >= start_ns, parent, dict(meta)))
+
+    with spans.recording(Sink()):
+        with spans.span("outer", decision=7) as outer:
+            with spans.span("inner"):
+                pass
+            outer.set(tried=3)
+    assert got == [("inner", True, "outer", {}),
+                   ("outer", True, None, {"decision": 7, "tried": 3})]
+    assert spans.span("after") is spans.span("after")   # detached again
+
+
+def test_tracer_has_no_private_wall_origin():
+    obs = Observability(name="t")
+    assert not hasattr(obs.tracer, "_wall0")
+    assert not hasattr(obs, "wall_elapsed_s")
+    t0 = spans.clock_ns()
+    with obs.recording(), spans.span("probe"):
+        pass
+    doc = obs.trace_document()
+    (ev,) = [e for e in doc["traceEvents"] if e.get("name") == "probe"]
+    assert doc["otherData"]["clock_origin_ns"] == spans.ORIGIN_NS
+    assert abs(spans.ORIGIN_NS + ev["ts"] * 1e3 - t0) < 1e6

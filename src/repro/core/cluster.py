@@ -45,6 +45,8 @@ caches and memoized ratios can never serve pre-mutation answers.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 from repro.core.types import ClusterSpec, Job, NodeSpec
@@ -57,6 +59,19 @@ _MISS = object()   # cache sentinel (cached values may legitimately be None)
 def _job_shape(job: Job) -> tuple:
     """The fields placement feasibility depends on — the cache key."""
     return (job.num_gpus, job.gpu_type, job.req_cpus, job.req_mem_gb)
+
+
+@dataclasses.dataclass(slots=True)
+class JobShape:
+    """A ``_job_shape`` key in a job's place: placement queries read only
+    these four fields (slots, as ``Job`` has: the searches read them per
+    node), so ``candidate_ways(JobShape(*key))`` shares the cache entries
+    of every job of that shape."""
+
+    num_gpus: int
+    gpu_type: str
+    req_cpus: int
+    req_mem_gb: float
 
 
 class ClusterState:
@@ -261,6 +276,11 @@ class ClusterState:
 
     def num_ways_to_schedule(self, job: Job) -> int:
         return len(self.candidate_ways(job))
+
+    def num_ways_for_shape(self, shape: tuple) -> int:
+        """``num_ways_to_schedule`` of any job whose ``_job_shape`` is
+        ``shape``, from the same cache entry, with no job object."""
+        return len(self.candidate_ways(JobShape(*shape)))
 
     # -------------------------------------------------------------- mutation ----
     def allocate(self, job: Job, placement: Placement) -> None:

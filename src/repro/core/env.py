@@ -88,8 +88,8 @@ class RLPrioritizer:
     def rank_window(self, jobs: list[Job], cluster: ClusterState, now: float,
                     fields) -> list[int]:
         """``rank`` over the engine's contiguous ``WindowFields`` views: the
-        FBM feature matrix is built with vectorized column ops instead of
-        the O(window * 17) scalar loop — bit-identical features, hence
+        FBM feature matrix is built by shape and by column instead of the
+        O(window * 17) scalar loop — bit-identical features, hence
         bit-identical actions and ranking (differential-pinned)."""
         return self._rank(jobs, cluster, now, fields)
 
@@ -97,7 +97,7 @@ class RLPrioritizer:
         n = min(len(jobs), MAX_QUEUE_SIZE)
         deep = self.deep_scorer is not None and len(jobs) > MAX_QUEUE_SIZE
         tail_logits = None
-        with span("rank.features", rows=len(jobs)):
+        with span("rank.features", rows=len(jobs)) as sp:
             if deep:
                 # one FBM pass over the whole window: the head state is
                 # built from the exact rows build_state would produce
@@ -119,6 +119,10 @@ class RLPrioritizer:
                                            use_estimates=self.use_estimates,
                                            raw=self.raw_features,
                                            fields=fields)
+            if fields is not None:
+                # distinct shapes behind the rows: the feature build's
+                # per-shape work (memoized on the view it just used)
+                sp.set(shapes=int(fields.present_shapes().size))
         if deep:
             tail_logits = self.deep_scorer.score(ov_full[n:])
         action, logits = self.agent.act(ov, cv, mask, explore=self.explore,

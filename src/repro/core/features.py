@@ -9,14 +9,17 @@ Two construction paths, bit-identical by contract (differential-pinned in
 
 - the retained scalar loop (O(window * 17) Python work per decision) — the
   reference, and the fallback when no field arrays are available;
-- a vectorized path over the engine's incrementally-maintained
-  ``WindowFields`` views (``fields=...``): all arithmetic features become
-  whole-column numpy ops; only the placement-dependent ``ways`` query (one
-  memoized call per distinct job *shape*, not per job) and the non-numeric
-  gathers (``gpu_type`` strings, CPU/mem requests) stay per-job.  Float
-  results are identical because every vector op applies the same IEEE
-  operation to the same float64 operands the scalar loop used, in the same
-  order, before the single float32 store.
+- a columnar path over the engine's incrementally-maintained
+  ``WindowFields`` views (``fields=...``): per-row work is numpy only.
+  Every value that depends on a job only through its shape (the
+  placement-dependent ``ways`` query, SKU index, free GPUs of the SKU,
+  CPU/mem requests, GPU demand) is computed once per distinct shape
+  present — free GPUs once per SKU present — and gathered to the rows
+  through the shape id.  Float results are identical because the
+  per-shape values come from the scalar loop's own expressions, and every
+  vector op applies the same IEEE operation to the same float64 operands
+  the scalar loop used, in the same order, before the single float32
+  store.
 """
 from __future__ import annotations
 
@@ -63,9 +66,9 @@ def build_features(
     """(len(jobs), 17) feature matrix for the current queue at time `now`.
 
     With ``fields`` (the engine's ``WindowFields`` views, aligned
-    index-for-index with ``jobs``) the matrix is built with vectorized
-    column ops; otherwise the retained scalar reference loop runs.  Both
-    paths are bit-identical (differential-pinned)."""
+    index-for-index with ``jobs``) the matrix is built by shape and by
+    column; otherwise the retained scalar reference loop runs.  Both paths
+    are bit-identical (differential-pinned)."""
     if fields is not None and len(jobs) == fields.submit_time.shape[0]:
         return _build_features_vec(jobs, cluster, now, fields,
                                    use_estimates=use_estimates)
@@ -152,15 +155,15 @@ def _build_features_vec(
     *,
     use_estimates: bool = False,
 ) -> np.ndarray:
-    """Vectorized FBM over the engine's contiguous field arrays.  Scalars
+    """Columnar FBM over the engine's contiguous field arrays.  Scalars
     that the loop recomputed per job (cluster aggregates, queued demand)
-    are hoisted; per-job Python work shrinks to the placement-dependent
-    ``ways`` query (memoized per distinct job shape) and the non-numeric
-    gathers (``gpu_type``, CPU/mem requests) the field views don't carry."""
+    are hoisted; the shape-dependent columns are filled once per distinct
+    shape present (Python work per shape, and per SKU for the free-GPU
+    query) and gathered to the rows with one fancy index; the remaining
+    per-row columns are whole-column numpy ops.  No Python runs per row."""
     n = len(jobs)
-    out = np.zeros((n, NUM_FEATURES), dtype=np.float32)
     if n == 0:
-        return out
+        return np.zeros((0, NUM_FEATURES), dtype=np.float32)
 
     # same placeable/provisioned capacity view as the scalar reference
     placeable = cluster.placeable_mask()
@@ -175,47 +178,48 @@ def _build_features_vec(
     # float64, so the float sum is the same value converted
     queued_demand = float(fields.num_gpus.sum())
 
+    # one row per shape present, of every column that depends on the job
+    # only through its shape (the scalar loop's expressions, so the same
+    # values), plus the window constants; free GPUs once per SKU present
+    present = fields.present_shapes()
+    free_of: dict[str, int] = {}
+    block = []
+    for sid in present:
+        gpus_s, gpu_type, cpus, mem = key = fields.shape_keys[sid]
+        free_t = free_of.get(gpu_type)
+        if free_t is None:
+            free_t = free_of[gpu_type] = cluster.free_gpus_of_type(gpu_type)
+        ways = cluster.num_ways_for_shape(key)
+        fa = (total_free - gpus_s - (queued_demand - gpus_s)) / total_capacity
+        row = [0.0] * NUM_FEATURES
+        row[_IDX["req_gpus"]] = _norm(gpus_s, 8.0)
+        row[_IDX["gpu_type_idx"]] = tindex[gpu_type] / max(len(gpu_types), 1)
+        row[_IDX["req_cpu"]] = _norm(cpus, 64.0)
+        row[_IDX["req_mem"]] = _norm(mem, 512.0)
+        row[_IDX["free_nodes"]] = free_nodes / max(len(cluster.gpu_types), 1)
+        row[_IDX["can_schedule_now"]] = 1.0 if ways > 0 else 0.0
+        row[_IDX["num_ways_to_schedule"]] = ways / 4.0
+        row[_IDX["dsr"]] = _norm(gpus_s / max(free_t, 1), 1.0)
+        # np.clip's value, NaN included, without its per-call overhead
+        row[_IDX["future_avail"]] = min(max(fa, -1.0), 1.0)
+        row[_IDX["cff"]] = cff
+        block.append(row)
+    # shape id -> row of `block`, then one gather makes the window matrix
+    row_of = np.empty(int(present[-1]) + 1, dtype=np.intp)
+    row_of[present] = np.arange(present.size)
+    out = np.array(block, dtype=np.float32)[
+        row_of[fields.shape_id.astype(np.intp)]]
+
     rt = fields.est_runtime if use_estimates else fields.runtime
     gpus = fields.num_gpus
     wait = np.maximum(0.0, now - fields.submit_time)
-
-    # per-job placement queries: one memoized call per distinct shape
-    jt = [j.gpu_type for j in jobs]
-    ways = np.empty(n, dtype=np.float64)
-    shape_ways: dict[tuple, int] = {}
-    for k, j in enumerate(jobs):
-        key = (j.num_gpus, j.gpu_type, j.req_cpus, j.req_mem_gb)
-        w = shape_ways.get(key)
-        if w is None:
-            w = cluster.num_ways_to_schedule(j)
-            shape_ways[key] = w
-        ways[k] = w
-    free_t_map = {t: cluster.free_gpus_of_type(t) for t in set(jt)}
-    free_t = np.array([free_t_map[t] for t in jt], dtype=np.float64)
-    type_idx = np.array([tindex[t] for t in jt], dtype=np.float64)
-    req_cpus = np.array([j.req_cpus for j in jobs], dtype=np.float64)
-    req_mem = np.array([j.req_mem_gb for j in jobs], dtype=np.float64)
-    job_ids = np.array([j.job_id for j in jobs], dtype=np.float64)
-
-    fa = (total_free - gpus - (queued_demand - gpus)) / total_capacity
-
-    out[:, _IDX["job_id"]] = np.mod(job_ids, 1000.0) / 1000.0
+    out[:, _IDX["job_id"]] = np.mod(fields.job_id, 1000.0) / 1000.0
     out[:, _IDX["user"]] = np.mod(fields.user, 128.0) / 128.0
-    out[:, _IDX["req_gpus"]] = _vnorm(gpus, 8.0)
     out[:, _IDX["vc"]] = fields.vc / 8.0
-    out[:, _IDX["gpu_type_idx"]] = type_idx / max(len(gpu_types), 1)
     out[:, _IDX["req_time"]] = _vnorm(rt, 8 * 3600.0)
     out[:, _IDX["submit_time"]] = _vnorm(wait, 3600.0)
-    out[:, _IDX["req_cpu"]] = _vnorm(req_cpus, 64.0)
-    out[:, _IDX["req_mem"]] = _vnorm(req_mem, 512.0)
-    out[:, _IDX["free_nodes"]] = free_nodes / max(len(cluster.gpu_types), 1)
-    out[:, _IDX["can_schedule_now"]] = (ways > 0).astype(np.float32)
-    out[:, _IDX["num_ways_to_schedule"]] = ways / 4.0
-    out[:, _IDX["dsr"]] = _vnorm(gpus / np.maximum(free_t, 1.0), 1.0)
     out[:, _IDX["job_size"]] = _vnorm(gpus * rt, 8.0 * 3600.0 * 8.0)
     out[:, _IDX["urgency"]] = _vnorm(wait / np.maximum(rt, 60.0), 4.0)
-    out[:, _IDX["future_avail"]] = np.clip(fa, -1.0, 1.0)
-    out[:, _IDX["cff"]] = cff
     # same NaN/inf guard as the scalar reference (identity on finite values)
     return np.nan_to_num(out, nan=0.0, posinf=1.0, neginf=-1.0)
 

@@ -2,11 +2,11 @@
 streaming engine (leaf module: keeps repro.core <-> repro.sched acyclic)."""
 from __future__ import annotations
 
-from typing import Protocol
+from typing import Protocol, Sequence
 
 import numpy as np
 
-from repro.core.cluster import ClusterState
+from repro.core.cluster import ClusterState, _job_shape
 from repro.core.policies import Policy
 from repro.core.types import Job
 
@@ -18,27 +18,43 @@ class WindowFields:
     The streaming engine maintains these arrays incrementally alongside its
     indexed pending queue and passes O(1) views per decision, so batch
     scoring never re-gathers Python attributes.  Arrays are read-only by
-    convention; integer-valued fields (``num_gpus``, ``user``, ``vc``) are
-    stored as float64 — exact for any realistic value (< 2**53), and float
-    keys hash/compare equal to the original ints so dict-based policy state
-    (fair-share usage, runtime history) stays collision-free.
+    convention; integer-valued fields (``num_gpus``, ``user``, ``vc``,
+    ``job_id``) are stored as float64 — exact for any realistic value
+    (< 2**53), and float keys hash/compare equal to the original ints so
+    dict-based policy state (fair-share usage, runtime history) stays
+    collision-free.
+
+    ``shape_id`` is each row's id in ``shape_keys``, the interned table of
+    ``_job_shape`` keys ``(num_gpus, gpu_type, req_cpus, req_mem_gb)``.
+    Everything that depends on a job only through its shape (placement
+    ways, SKU, CPU and memory requests) is then computed once per distinct
+    shape present and gathered to the rows with numpy; no per-row Python
+    runs over the window.
     """
 
     __slots__ = ("submit_time", "runtime", "est_runtime", "num_gpus",
-                 "user", "vc")
+                 "user", "vc", "shape_id", "job_id", "shape_keys",
+                 "_present")
 
     def __init__(self, submit_time: np.ndarray, runtime: np.ndarray,
                  est_runtime: np.ndarray, num_gpus: np.ndarray,
-                 user: np.ndarray, vc: np.ndarray):
+                 user: np.ndarray, vc: np.ndarray, shape_id: np.ndarray,
+                 job_id: np.ndarray, shape_keys: Sequence[tuple]):
         self.submit_time = submit_time
         self.runtime = runtime
         self.est_runtime = est_runtime
         self.num_gpus = num_gpus
         self.user = user
         self.vc = vc
+        self.shape_id = shape_id
+        self.job_id = job_id
+        self.shape_keys = shape_keys
+        self._present = None
 
     @classmethod
     def from_jobs(cls, jobs: list[Job]) -> "WindowFields":
+        ids: dict[tuple, int] = {}
+        sid = [ids.setdefault(_job_shape(j), len(ids)) for j in jobs]
         return cls(
             np.array([j.submit_time for j in jobs], dtype=np.float64),
             np.array([j.runtime for j in jobs], dtype=np.float64),
@@ -46,6 +62,9 @@ class WindowFields:
             np.array([j.num_gpus for j in jobs], dtype=np.float64),
             np.array([j.user for j in jobs], dtype=np.float64),
             np.array([j.vc for j in jobs], dtype=np.float64),
+            np.array(sid, dtype=np.float64),
+            np.array([j.job_id for j in jobs], dtype=np.float64),
+            list(ids),
         )
 
     def take(self, indices: list[int]) -> "WindowFields":
@@ -54,7 +73,16 @@ class WindowFields:
         ix = np.asarray(indices, dtype=np.intp)
         return WindowFields(self.submit_time[ix], self.runtime[ix],
                             self.est_runtime[ix], self.num_gpus[ix],
-                            self.user[ix], self.vc[ix])
+                            self.user[ix], self.vc[ix], self.shape_id[ix],
+                            self.job_id[ix], self.shape_keys)
+
+    def present_shapes(self) -> np.ndarray:
+        """Sorted ids of the shapes present in the window (computed once
+        per view)."""
+        if self._present is None:
+            self._present = np.flatnonzero(
+                np.bincount(self.shape_id.astype(np.intp)))
+        return self._present
 
 
 class Prioritizer(Protocol):

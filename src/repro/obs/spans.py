@@ -80,14 +80,16 @@ class _Span:
         self._sinks = ()
 
     def __enter__(self):
-        if _profiling():
-            self._ann = TraceAnnotation(self.name, **self.meta)
-            self._ann.__enter__()
+        # the TraceMe opens last and closes first, so the profile's event
+        # and the clock pair enclose the same work, not the sinks' upkeep
         if _sinks:
             self._sinks = tuple(_sinks)
             names = _open_names()
             self._parent = names[-1] if names else None
             names.append(self.name)
+        if _profiling():
+            self._ann = TraceAnnotation(self.name, **self.meta)
+            self._ann.__enter__()
         self._t0 = clock_ns()
         return self
 
@@ -98,16 +100,16 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = clock_ns()
-        if self._sinks:
-            _open_names().pop()
-            for sink in self._sinks:
-                sink.record_span(self.name, self._t0, t1, self._parent,
-                                 self.meta)
         if self._ann is not None:
             self._ann.__exit__(*exc)
             tot = _traced.setdefault(self.name, [0, 0])
             tot[0] += 1
             tot[1] += t1 - self._t0
+        if self._sinks:
+            _open_names().pop()
+            for sink in self._sinks:
+                sink.record_span(self.name, self._t0, t1, self._parent,
+                                 self.meta)
         return None
 
 

@@ -90,17 +90,19 @@ class _PendingFieldIndex:
     Inserts/removals memmove the suffix (C-speed, amortized cheap next to
     the O(window) Python work they replace); the ranking window is then a
     free O(1) slice view per field, so batch scoring never re-gathers job
-    attributes.  Integer-valued fields (``num_gpus``, ``user``, ``vc``)
-    are stored as float64 — exact for any realistic value (< 2**53).
+    attributes.  Integer-valued fields (``num_gpus``, ``user``, ``vc``,
+    ``job_id``) are stored as float64 — exact for any realistic value
+    (< 2**53).
 
     ``_sid`` carries a small-int **shape id** per job (interned
-    ``_job_shape`` key): placement feasibility is a pure function of
-    (shape, cluster version), so the deep-backfill scan can skip a
-    shape it already saw fail at the current version without touching
-    the job object at all."""
+    ``_job_shape`` key; ``shape_keys[sid]`` is the key): placement
+    feasibility is a pure function of (shape, cluster version), so the
+    deep-backfill scan can skip a shape it already saw fail at the current
+    version without touching the job object at all, and the feature build
+    computes its per-shape values once per shape present."""
 
     __slots__ = ("n", "_cap", "_st", "_rt", "_est", "_gpus", "_user", "_vc",
-                 "_sid", "shape_ids")
+                 "_sid", "_jid", "shape_ids", "shape_keys")
 
     def __init__(self, cap: int = 256):
         self.n = 0
@@ -112,18 +114,21 @@ class _PendingFieldIndex:
         self._user = np.empty(cap, dtype=np.float64)
         self._vc = np.empty(cap, dtype=np.float64)
         self._sid = np.empty(cap, dtype=np.float64)
+        self._jid = np.empty(cap, dtype=np.float64)
         self.shape_ids: dict[tuple, int] = {}
+        self.shape_keys: list[tuple] = []
 
     def _arrays(self):
         return (self._st, self._rt, self._est, self._gpus, self._user,
-                self._vc, self._sid)
+                self._vc, self._sid, self._jid)
 
     def _shape_id(self, job: Job) -> int:
         key = _job_shape(job)
         sid = self.shape_ids.get(key)
         if sid is None:
-            sid = len(self.shape_ids)
+            sid = len(self.shape_keys)
             self.shape_ids[key] = sid
+            self.shape_keys.append(key)
         return sid
 
     def insert(self, idx: int, job: Job) -> None:
@@ -136,11 +141,11 @@ class _PendingFieldIndex:
                 g[:n] = a[:n]
                 grown.append(g)
             (self._st, self._rt, self._est, self._gpus, self._user,
-             self._vc, self._sid) = grown
+             self._vc, self._sid, self._jid) = grown
         for a, v in zip(self._arrays(),
                         (job.submit_time, job.runtime, job.est_runtime,
                          job.num_gpus, job.user, job.vc,
-                         self._shape_id(job))):
+                         self._shape_id(job), job.job_id)):
             a[idx + 1:n + 1] = a[idx:n]
             a[idx] = v
         self.n = n + 1
@@ -154,7 +159,8 @@ class _PendingFieldIndex:
     def window(self, w: int) -> WindowFields:
         w = min(w, self.n)
         return WindowFields(self._st[:w], self._rt[:w], self._est[:w],
-                            self._gpus[:w], self._user[:w], self._vc[:w])
+                            self._gpus[:w], self._user[:w], self._vc[:w],
+                            self._sid[:w], self._jid[:w], self.shape_keys)
 
 
 class EngineHooks:

@@ -71,6 +71,7 @@ def test_features_bounded(now, use_est):
 # bit-identical (same float32 matrix, bit for bit) so RL schedules and
 # training trajectories cannot drift.
 
+from repro.core.cluster import _job_shape  # noqa: E402
 from repro.core.features import _build_features_scalar  # noqa: E402
 from repro.core.prioritizer import WindowFields  # noqa: E402
 
@@ -85,17 +86,63 @@ def _varied_cluster(trace, seed):
     return c
 
 
+#: the windows the columnar build is held to the scalar loop on
+WINDOWS = ("from_jobs", "deep", "absent", "take", "cordoned")
+
+
+def _index(jobs):
+    """The engine's pending-field index over ``jobs``, row for row."""
+    from repro.sched.engine import _PendingFieldIndex
+    pi = _PendingFieldIndex()
+    for k, j in enumerate(jobs):
+        pi.insert(k, j)
+    return pi
+
+
+def _window(kind, trace, seed, n):
+    """``(jobs, fields, cluster)`` of one window kind: ``from_jobs``-built
+    fields; the engine index's views over a 4,096-row window (``deep``);
+    index views after every row of some interned shapes left (``absent``);
+    a ``take()`` subset; a cluster with a downed, a cordoned and a retired
+    node (``cordoned``)."""
+    jobs = generate_trace(trace, 4096 if kind == "deep" else n, seed=seed)
+    c = _varied_cluster(trace, seed)
+    if kind == "cordoned":
+        busy = np.flatnonzero(c.free_gpus < c.total_gpus)
+        idle = np.flatnonzero(c.free_gpus == c.total_gpus)
+        assert busy.size and idle.size >= 2
+        assert not c.remove_node(int(busy[0]))     # cordoned, draining
+        assert c.remove_node(int(idle[0]))         # retired
+        c.fail_node(int(idle[-1]))
+    if kind in ("deep", "absent"):
+        pi = _index(jobs)
+        if kind == "absent":
+            gone = {pi.shape_ids[key] for key in
+                    (_job_shape(jobs[0]), _job_shape(jobs[-1]))}
+            for k in reversed(range(len(jobs))):
+                if pi._sid[k] in gone:
+                    pi.remove(k)
+                    del jobs[k]
+        fields = pi.window(len(jobs))
+        if kind == "absent":
+            assert len(fields.shape_keys) > fields.present_shapes().size
+        return jobs, fields, c
+    fields = WindowFields.from_jobs(jobs)
+    if kind == "take":
+        keep = [k for k in range(len(jobs)) if k % 3]
+        return [jobs[k] for k in keep], fields.take(keep), c
+    return jobs, fields, c
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.sampled_from(["helios", "philly", "alibaba"]),
        st.integers(min_value=0, max_value=10_000),
-       st.booleans())
-def test_vectorized_features_bit_identical(trace, seed, use_est):
-    jobs = generate_trace(trace, 64, seed=seed % 997)
-    c = _varied_cluster(trace, seed % 31)
+       st.booleans(), st.sampled_from(WINDOWS))
+def test_vectorized_features_bit_identical(trace, seed, use_est, window):
+    jobs, fields, c = _window(window, trace, seed % 997, 64)
     now = jobs[len(jobs) // 2].submit_time + float(seed % 7919)
     ref = _build_features_scalar(jobs, c, now, use_estimates=use_est)
-    vec = build_features(jobs, c, now, use_estimates=use_est,
-                         fields=WindowFields.from_jobs(jobs))
+    vec = build_features(jobs, c, now, use_estimates=use_est, fields=fields)
     assert vec.dtype == ref.dtype
     assert np.array_equal(ref, vec)
 
@@ -162,21 +209,69 @@ def test_rl_stream_rank_window_schedule_identical():
     assert fins[0] == fins[1]
 
 
-@pytest.mark.parametrize("trace,seed,use_est", [
-    ("helios", 0, False), ("helios", 13, True),
-    ("philly", 4, False), ("philly", 7, True),
-    ("alibaba", 2, False), ("alibaba", 29, True),
+@pytest.mark.parametrize("trace,seed,use_est,window", [
+    pytest.param("helios", 0, False, "from_jobs", id="helios-0-False"),
+    pytest.param("helios", 13, True, "from_jobs", id="helios-13-True"),
+    pytest.param("philly", 4, False, "from_jobs", id="philly-4-False"),
+    pytest.param("philly", 7, True, "from_jobs", id="philly-7-True"),
+    pytest.param("alibaba", 2, False, "from_jobs", id="alibaba-2-False"),
+    pytest.param("alibaba", 29, True, "from_jobs", id="alibaba-29-True"),
+    pytest.param("helios", 5, False, "deep", id="helios-5-False-deep"),
+    pytest.param("philly", 8, True, "deep", id="philly-8-True-deep"),
+    pytest.param("helios", 3, False, "absent", id="helios-3-False-absent"),
+    pytest.param("alibaba", 6, True, "absent", id="alibaba-6-True-absent"),
+    pytest.param("philly", 1, False, "take", id="philly-1-False-take"),
+    pytest.param("alibaba", 9, True, "take", id="alibaba-9-True-take"),
+    pytest.param("helios", 2, True, "cordoned",
+                 id="helios-2-True-cordoned"),
+    pytest.param("philly", 11, False, "cordoned",
+                 id="philly-11-False-cordoned"),
 ])
-def test_vectorized_features_bit_identical_fixed(trace, seed, use_est):
+def test_vectorized_features_bit_identical_fixed(trace, seed, use_est, window):
     """Deterministic cover for the differential (the hypothesis variant is
     skipped on minimal installs without the [test] extra)."""
-    jobs = generate_trace(trace, 96, seed=seed)
-    c = _varied_cluster(trace, seed)
-    now = jobs[48].submit_time + 123.0
+    jobs, fields, c = _window(window, trace, seed, 96)
+    now = jobs[len(jobs) // 2].submit_time + 123.0
     ref = _build_features_scalar(jobs, c, now, use_estimates=use_est)
-    vec = build_features(jobs, c, now, use_estimates=use_est,
-                         fields=WindowFields.from_jobs(jobs))
+    vec = build_features(jobs, c, now, use_estimates=use_est, fields=fields)
     assert np.array_equal(ref, vec)
+
+
+def test_engine_index_columns_follow_the_pending_queue():
+    """The pending index's ``_jid`` and ``_sid`` columns equal the pending
+    jobs' own ids and shapes after inserts, removals and array grows, and
+    again after a ``save_state`` / ``load_state`` round trip; the window's
+    columnar features equal the scalar loop's throughout."""
+    from repro.core import PolicyPrioritizer, make_policy
+    from repro.sched import SchedulerEngine, get_scenario
+
+    def check(eng):
+        pi, pending = eng._pindex, eng.pending
+        assert pi.n == len(pending) > 0
+        assert pi._jid[:pi.n].tolist() == [float(j.job_id) for j in pending]
+        assert [pi.shape_keys[int(s)] for s in pi._sid[:pi.n]] \
+            == [_job_shape(j) for j in pending]
+        w = min(eng.queue_window, pi.n)
+        assert np.array_equal(
+            build_features(pending[:w], eng.cluster, eng.now,
+                           fields=pi.window(w)),
+            _build_features_scalar(pending[:w], eng.cluster, eng.now))
+
+    run = get_scenario("overcommit-queue").build(900, seed=4)
+    eng = SchedulerEngine(run.spec, PolicyPrioritizer(make_policy("sjf")),
+                          allocator="pack")
+    eng.submit([j.clone_pending() for j in run.jobs])
+    mid = sorted(j.submit_time for j in run.jobs)[700]
+    eng.step(until=mid)
+    assert eng._pindex._cap > 256 and eng.completed   # grown, and removed
+    check(eng)
+    back = SchedulerEngine.load_state(eng.save_state())
+    check(back)
+    for e in (eng, back):
+        e.step(until=mid + 3600.0)
+    check(back)
+    assert back._pindex._jid[:back._pindex.n].tolist() \
+        == eng._pindex._jid[:eng._pindex.n].tolist()
 
 
 # ------------------------------------------------------- edge-case coverage --

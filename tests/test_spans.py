@@ -9,12 +9,13 @@ import jax
 import pytest
 
 from repro.core.agent import PPOAgent
+from repro.core.cluster import _job_shape
 from repro.core.env import RLPrioritizer
 from repro.kernels.batch_score import BucketedScorer
 from repro.obs import Observability, spans, validate_trace
 from repro.obs.report import analyze
 from repro.predict import RuntimePredictor
-from repro.sched import get_scenario, run_stream
+from repro.sched import EngineHooks, get_scenario, run_stream
 
 #: span -> the program spans it may nest in directly (None: none of them)
 PARENTS = {
@@ -34,7 +35,17 @@ PARENTS = {
 QUEUE_WINDOW = 320      # deeper than the actor's 256 slots: the scorer runs
 
 
-def _deep_stream(obs=None):
+class _WindowShapes(EngineHooks):
+    """Rows and distinct ``_job_shape`` keys of every ranking window."""
+
+    def __init__(self):
+        self.windows = []
+
+    def on_decision(self, jobs, order, now, engine):
+        self.windows.append((len(jobs), len({_job_shape(j) for j in jobs})))
+
+
+def _deep_stream(obs=None, hooks=()):
     """An overcommitted queue over three SKUs (so the MILP runs, gangs
     block the head and backfill starts jobs behind it) ranked by the
     actor and, past 256 waiting jobs, the deep scorer; with a shadow
@@ -45,7 +56,8 @@ def _deep_stream(obs=None):
                         deep_scorer=BucketedScorer(agent.params["actor"]))
     res = run_stream(run.spec, [j.clone_pending() for j in run.jobs], pri,
                      queue_window=QUEUE_WINDOW, chunked_submit=True,
-                     predictor=RuntimePredictor(assist=False), obs=obs)
+                     predictor=RuntimePredictor(assist=False), obs=obs,
+                     hooks=hooks)
     eng = res.engine
     return (tuple(sorted((j.job_id, j.first_start_time, j.finish_time)
                          for j in eng.completed)),
@@ -66,10 +78,11 @@ def profiled(tmp_path_factory):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     obs = Observability(name="t")
+    windows = _WindowShapes()
     before = spans.traced_totals()
     jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
-        sig = _deep_stream(obs)
+        sig = _deep_stream(obs, hooks=(windows,))
     finally:
         jax.profiler.stop_trace()
     totals = {n: (c - before.get(n, (0, 0))[0], v - before.get(n, (0, 0))[1])
@@ -88,7 +101,7 @@ def profiled(tmp_path_factory):
                                    e.start_ns + e.duration_ns,
                                    dict(e.stats)))
     return {"sig": sig, "obs": obs, "events": events, "start_ns": start,
-            "totals": totals}
+            "totals": totals, "windows": windows.windows}
 
 
 def _parents(events):
@@ -145,6 +158,18 @@ def test_decision_id_is_shared_by_the_decisions_spans(profiled, baseline):
     for meta, decide in scored:
         assert meta["rows"] == decide["window"] - 256 > 0
         assert meta["bucket"] == 256
+
+
+def test_feature_span_counts_rows_and_shapes(profiled):
+    """Each ``rank.features`` span carries its window's rows and the
+    number of distinct job shapes in it, the feature build's per-shape
+    work."""
+    feats = sorted((ev for ev in profiled["events"]
+                    if ev[0] == "rank.features"), key=lambda ev: ev[1])
+    got = [(meta["rows"], meta["shapes"]) for _, _, _, meta in feats]
+    assert got == profiled["windows"]
+    assert max(rows for rows, _ in got) > 256      # deep windows among them
+    assert all(0 < shapes <= rows for rows, shapes in got)
 
 
 def test_traced_totals_sum_the_profiles_events(profiled):

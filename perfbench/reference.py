@@ -8,7 +8,9 @@ nothing from the program.
 
 The schedule (start instants, placements and the decision counters) is
 compared with the program's retained reference loop (``optimized=False``)
-replayed from the engine's state saved at the window's start.
+replayed from the engine's state saved at the window's start, with the
+program's prioritizer chain as the timed run had it (the harness's
+traced-run wrapper taken out).
 
 ``control_*`` compute the same forward one precision step lower
 (bfloat16 operands, float32 accumulation) on the device; put in the
@@ -20,7 +22,7 @@ import pickle
 
 import numpy as np
 
-from recorders import counters
+from recorders import TracedPrioritizer, counters
 
 
 def mlp64(layers, x) -> np.ndarray:
@@ -134,13 +136,33 @@ def control_forward(precision: str):
 def naive_blob(blob: bytes) -> bytes:
     """The engine state of ``blob`` switched to the program's reference
     loop: ``optimized=False`` and an uncached cluster, with the harness's
-    traced-run wrapper taken off the prioritizer."""
+    traced-run wrapper taken out of the prioritizer chain wherever it sits;
+    every program prioritizer in the chain (a VC-quota gate with its usage
+    included) stays."""
     state = pickle.loads(blob)
     state["optimized"] = False
     state["cluster"].cache_enabled = False
-    pri = state["prioritizer"]
-    state["prioritizer"] = getattr(pri, "base", pri)
+    state["prioritizer"] = without_traced(state["prioritizer"])
     return pickle.dumps(state, protocol=pickle.HIGHEST_PROTOCOL)
+
+
+def without_traced(pri):
+    """The prioritizer chain (each wrapper's ``base`` the next) with the
+    harness's ``TracedPrioritizer`` spliced out.  A wrapper that bound its
+    base's ``rank_window`` (the program's ``QuotaPrioritizer``) is rebound
+    to the new base."""
+    if isinstance(pri, TracedPrioritizer):
+        return pri.base
+    outer = pri
+    while (inner := getattr(outer, "base", None)) is not None:
+        if isinstance(inner, TracedPrioritizer):
+            outer.base = inner.base
+            if hasattr(outer, "_base_rank_window"):
+                outer._base_rank_window = getattr(inner.base, "rank_window",
+                                                  None)
+            break
+        outer = inner
+    return pri
 
 
 def replay_schedule(engine_cls, hooks_cls, blob: bytes, stream: list,

@@ -13,7 +13,9 @@ this file is particular to a cell.
 One run: generate the job stream from the seed and the deployment's
 actor weights, build the program's RLTune service loop
 (``repro.sched.run_stream`` with the PPO actor ranking, the deep-window
-scorer, the MILP allocator, EASY backfill and the runtime predictor),
+scorer, the MILP allocator, EASY backfill and the runtime predictor, and
+the program's VC-quota gate where the configuration states
+``scheduler.vc_quotas``),
 replay the stream until the traffic's warm-up instant, warm every shape the
 window will use, then measure for ``--seconds`` of wall time, ending at the
 next rescan-window edge.  The engine's state is saved when the window opens
@@ -305,7 +307,7 @@ def run(args) -> dict:
     from repro.core.types import ClusterSpec, Job, NodeSpec
     from repro.kernels.batch_score import BucketedScorer
     from repro.predict import RuntimePredictor
-    from repro.sched import MultiHooks, run_stream
+    from repro.sched import MultiHooks, run_stream, wrap_tenancy
     from repro.sched.engine import SchedulerEngine
 
     import guarantees
@@ -350,6 +352,11 @@ def run(args) -> dict:
         pri = tail
         hooks.append(clock)
         active += [clock, tail]
+    if sch.get("vc_quotas"):
+        # the program's VC-quota gate, outermost in every kind of run, so
+        # that the service loop feeds it the engine's starts and finishes
+        pri = wrap_tenancy(pri, vc_quotas={
+            int(vc): float(q) for vc, q in sch["vc_quotas"].items()})
 
     rows_lo, rows_hi = traffic["window_rows"]
     k_look = sch["lookahead_k"]
@@ -417,8 +424,9 @@ def run(args) -> dict:
 
     # ---- guarantees and reference comparison, after the window ------------
     t_ref = time.perf_counter()
-    broken, checked = guarantees.violations(cfg["cluster"], cols, start_log.starts,
-                                   start_log.decisions)
+    broken, checked = guarantees.violations(
+        cfg["cluster"], cols, start_log.starts, start_log.decisions, sch)
+    guarantees_s = time.perf_counter() - t_ref
     numbers = {"guarantee_violations": sum(broken.values())}
     samples = {k: [it for it in getattr(sampler, k).items if it is not None]
                for k in ("actor", "scorer", "predictor")}
@@ -525,7 +533,7 @@ def run(args) -> dict:
         "setup_parts_s": dict(zip(marks, np.diff(
             [T_PROCESS] + list(marks.values())).round(3).tolist())),
         "state_save_s": ctx["save_s"], "mid_save_s": loop.paused,
-        "reference_s": reference_s,
+        "reference_s": reference_s, "guarantees_s": guarantees_s,
         "window_edges": [ctx["t_from"], ctx["t_to"]],
         "reference_from": [s["t_from"] for s in segments],
         "reference_events": [r["events"] for r in replays],

@@ -10,7 +10,10 @@ program's own synthetic trace generator also reads them: a two-state
 Markov-modulated Poisson process of arrivals (calm / burst, switching per
 arrival), lognormal runtimes clipped to [30 s, max_runtime], user runtime
 estimates with lognormal noise, Zipf-like user popularity, and
-categorical GPU-demand and GPU-type mixes.  It is kept here, vectorized,
+categorical GPU-demand and GPU-type mixes, and each job's virtual cluster
+(VC): uniform over ``NUM_VCS`` ids, or drawn from the profile's optional
+``vc_share``, ``[[vc, share], ...]`` (a multi-tenant deployment's demand
+per VC; shares are normalised).  It is kept here, vectorized,
 so that the benchmark's traffic does not move when the program's
 generator does.
 
@@ -40,7 +43,8 @@ import math
 
 import numpy as np
 
-#: number of virtual-cluster ids a job is drawn from (uniform)
+#: number of virtual-cluster ids a job is drawn from where the profile
+#: states no ``vc_share`` (uniform)
 NUM_VCS = 5
 #: grid of the submit instants, in seconds
 RESOLUTION_S = 1.0
@@ -76,7 +80,7 @@ def _profile_jobs(rng: np.random.Generator, prof: dict, n: int) -> dict:
     user_w = 1.0 / np.arange(1, users + 1) ** 1.1
     demand, dprob = zip(*prof["gpu_demand"])
     types, tprob = zip(*prof["gpu_types"])
-    return {
+    cols = {
         "runtime": runtime,
         "est": est,
         "user": rng.choice(users, size=n, p=user_w / user_w.sum()),
@@ -84,8 +88,16 @@ def _profile_jobs(rng: np.random.Generator, prof: dict, n: int) -> dict:
                            p=np.asarray(dprob) / sum(dprob)),
         "gpu_type": rng.choice(np.asarray(types), size=n,
                                p=np.asarray(tprob) / sum(tprob)),
-        "vc": rng.integers(0, NUM_VCS, size=n),
     }
+    # the VC is drawn last: a vc_share changes no other column of these jobs
+    share = prof.get("vc_share")
+    if share:
+        vcs, vprob = zip(*share)
+        cols["vc"] = rng.choice(np.asarray(vcs, dtype=np.int64), size=n,
+                                p=np.asarray(vprob) / sum(vprob))
+    else:
+        cols["vc"] = rng.integers(0, NUM_VCS, size=n)
+    return cols
 
 
 def stream_columns(prof: dict, traffic: dict, seed: int) -> dict:

@@ -39,7 +39,7 @@ def test_sound_schedule_passes():
     starts, decisions = sound()
     bad, seen = guarantees.violations(CLUSTER, cols(), starts, decisions)
     assert bad == dict.fromkeys(guarantees.KINDS, 0)
-    assert seen == {"starts": 5, "backfills": 1}
+    assert seen == {"starts": 5, "backfills": 1, "quota_decisions": 0}
 
 
 def broken_capacity(s, d):
@@ -88,3 +88,57 @@ def test_start_outside_a_decision_is_counted():
     starts, decisions = sound()
     bad, _ = guarantees.violations(CLUSTER, cols(), starts, decisions[1:])
     assert bad["order"] >= 1, bad
+
+
+# quota: job: vc, gpus, type, runtime, submit; the slice has 16 GPUs
+QUOTA_JOBS = [(0, 8, "V100", 1000.0, 0.0),   # 0: VC 0 at 8/16 once started
+              (0, 1, "P100", 100.0, 10.0),   # 1: VC 0 again
+              (1, 4, "P100", 100.0, 10.0)]   # 2: VC 1, under its quota
+QUOTAS = {"vc_quotas": {"0": 0.25, "1": 0.25}, "queue_window": 2}
+
+
+def quota_cols():
+    vc, g, ty, rt, sub = zip(*QUOTA_JOBS)
+    return {"vc": np.array(vc), "gpus": np.array(g), "gpu_type": np.array(ty),
+            "runtime": np.array(rt), "submit": np.array(sub)}
+
+
+def quota_schedule(first, second):
+    """Job 0 starts at t=0; at t=10 two decisions start ``first`` then
+    ``second`` (each the head of its decision)."""
+    place = {1: ((0, 1),), 2: ((1, 4),)}
+    starts = [(0.0, 0, ((2, 8),)), (10.0, first, place[first]),
+              (10.0, second, place[second])]
+    decisions = [(0.0, 0, 0), (10.0, first, 1), (10.0, second, 2)]
+    return starts, decisions
+
+
+def test_quota_gate_order_passes():
+    bad, seen = guarantees.violations(CLUSTER, quota_cols(),
+                                      *quota_schedule(2, 1), QUOTAS)
+    assert bad == dict.fromkeys(guarantees.KINDS, 0)
+    assert seen["quota_decisions"] == 2
+
+
+def test_over_quota_head_before_a_waiting_under_quota_job_is_counted():
+    bad, seen = guarantees.violations(CLUSTER, quota_cols(),
+                                      *quota_schedule(1, 2), QUOTAS)
+    assert bad["quota"] == 1, bad
+    assert sum(bad.values()) == 1
+    assert seen["quota_decisions"] == 1
+
+
+def test_under_quota_job_beyond_the_window_is_not_counted():
+    bad, seen = guarantees.violations(CLUSTER, quota_cols(),
+                                      *quota_schedule(1, 2),
+                                      dict(QUOTAS, queue_window=1))
+    assert bad == dict.fromkeys(guarantees.KINDS, 0)
+    assert seen["quota_decisions"] == 1
+
+
+@pytest.mark.parametrize("scheduler", [None, {"queue_window": 2}])
+def test_no_vc_quotas_counts_no_quota(scheduler):
+    bad, seen = guarantees.violations(CLUSTER, quota_cols(),
+                                      *quota_schedule(1, 2), scheduler)
+    assert bad == dict.fromkeys(guarantees.KINDS, 0)
+    assert seen["quota_decisions"] == 0

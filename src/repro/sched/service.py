@@ -20,6 +20,8 @@ import dataclasses
 import math
 from typing import Callable
 
+import numpy as np
+
 from repro.core.faults import FaultModel
 from repro.core.metrics import BatchResult
 from repro.core.policies import make_policy
@@ -150,29 +152,50 @@ class QuotaPrioritizer(EngineHooks):
                 used[job.vc] = used.get(job.vc, 0) + job.num_gpus
         return used
 
-    def _gate(self, jobs, cluster, order):
-        used = self._vc_usage()
-        # provisioned (non-retired) capacity: VC shares must track elastic
-        # cluster size, and equal the raw total whenever autoscaling is off
-        total = max(cluster.provisioned_gpu_totals()[0], 1)
-        over = {vc for vc, q in self.quotas.items()
-                if used.get(vc, 0) / total > q}
-        under = [i for i in order if jobs[i].vc not in over]
-        demoted = [i for i in order if jobs[i].vc in over]
-        return under + demoted
+    def _gate(self, vc, cluster, order):
+        """Stable partition of the base ``order`` over the window's VC
+        column ``vc``: rows of VCs over quota fall behind every other row,
+        each side keeping its base order.  With nothing to demote the base
+        order comes back untouched; otherwise an ``np.intp`` array."""
+        with span("rank.quota", rows=len(order)) as sp:
+            used = self._vc_usage()
+            # provisioned (non-retired) capacity: VC shares must track
+            # elastic cluster size, and equal the raw total whenever
+            # autoscaling is off
+            total = max(cluster.provisioned_gpu_totals()[0], 1)
+            over = [v for v, q in self.quotas.items()
+                    if used.get(v, 0) / total > q]
+            if not over:
+                sp.set(demoted=0, over=0)
+                return order
+            idx = np.asarray(order, dtype=np.intp)
+            hit = np.isin(vc[idx], over)
+            demoted = int(np.count_nonzero(hit))
+            sp.set(demoted=demoted, over=len(over))
+            if not demoted:
+                return order
+            return np.concatenate((idx[~hit], idx[hit]))
 
     def rank(self, jobs, cluster, now):
-        return self._gate(jobs, cluster, self.base.rank(jobs, cluster, now))
+        """The gated order as the ``Prioritizer`` protocol's list (the
+        reference loop's path; the engine's fast path takes
+        ``rank_window``'s array as it is)."""
+        vc = np.fromiter((j.vc for j in jobs), np.float64, len(jobs))
+        order = self._gate(vc, cluster, self.base.rank(jobs, cluster, now))
+        return order.tolist() if isinstance(order, np.ndarray) else order
 
     def rank_window(self, jobs, cluster, now, fields):
-        """Full-window field pass-through to the base (the quota gate itself
-        is a stable partition of the base order, so gating the fields-path
-        ranking is bit-identical to gating ``base.rank``)."""
-        if self._base_rank_window is not None and fields is not None:
+        """Full-window field pass-through to the base; the gate reads the
+        window's ``vc`` column (the gate is a stable partition of the base
+        order, so gating the fields-path ranking is bit-identical to
+        gating ``base.rank``)."""
+        if fields is None:
+            return self.rank(jobs, cluster, now)
+        if self._base_rank_window is not None:
             order = self._base_rank_window(jobs, cluster, now, fields)
         else:
             order = self.base.rank(jobs, cluster, now)
-        return self._gate(jobs, cluster, order)
+        return self._gate(fields.vc, cluster, order)
 
     def observe_finish(self, job):
         self.base.observe_finish(job)

@@ -64,3 +64,59 @@ def helios_jobs():
 def helios_cluster():
     from repro.core import make_cluster
     return make_cluster("helios")
+
+
+
+#: the small PAI-shaped stream's queue window: deeper than the actor's 256
+#: slots, so the deep scorer ranks the tail
+PAI_WINDOW = 320
+
+
+def pai_quota_stream(*, optimized=True):
+    """A small stream shaped like the ``pai-quota`` cell: the
+    ``multi-tenant`` scenario (the Alibaba PAI slice over T4, P100 and
+    V100; four VCs with 55/25/12/8% of the demand and 25% quotas) with 360
+    of its 420 jobs queued at t = 0, ranked by the actor and, past 256
+    waiting jobs, the deep scorer, under the program's quota gate.
+
+    Returns the schedule's signature (every job's start and finish; the
+    decision, MILP and backfill counters) and, per decision, what the
+    plain list partition does there: the type of the order the engine was
+    handed, the window's rows, how many of them belong to VCs over quota
+    (the rows it demotes) and how many VCs are over quota."""
+    from repro.core.agent import PPOAgent
+    from repro.core.env import RLPrioritizer
+    from repro.kernels.batch_score import BucketedScorer
+    from repro.predict import RuntimePredictor
+    from repro.sched import (EngineHooks, get_scenario, run_stream,
+                             wrap_tenancy)
+
+    class Partitions(EngineHooks):
+        def __init__(self):
+            self.seen = []
+
+        def on_decision(self, jobs, order, now, engine):
+            pri = engine.prioritizer
+            total = max(engine.cluster.provisioned_gpu_totals()[0], 1)
+            over = {vc for vc, q in pri.quotas.items()
+                    if pri._usage.get(vc, 0) / total > q}
+            self.seen.append((type(order), len(jobs),
+                              sum(j.vc in over for j in jobs), len(over)))
+
+    run = get_scenario("multi-tenant").build(420, 0)
+    for job in run.jobs[:360]:
+        job.submit_time = 0.0
+    agent = PPOAgent()
+    pri = wrap_tenancy(
+        RLPrioritizer(agent, explore=False,
+                      deep_scorer=BucketedScorer(agent.params["actor"])),
+        vc_quotas=run.vc_quotas)
+    seen = Partitions()
+    res = run_stream(run.spec, [j.clone_pending() for j in run.jobs], pri,
+                     queue_window=PAI_WINDOW, chunked_submit=True,
+                     predictor=RuntimePredictor(assist=False),
+                     optimized=optimized, hooks=(seen,))
+    eng = res.engine
+    return (tuple(sorted((j.job_id, j.first_start_time, j.finish_time)
+                         for j in eng.completed)),
+            (eng.decisions, eng.milp_calls, eng.backfills)), seen.seen

@@ -1,12 +1,17 @@
 """Coverage for the tenancy prioritizer wrappers in repro.sched.service:
 the SLA bypass lane (previously untested) and the incremental VC-quota gate
 (differential-pinned against its O(running) recompute reference)."""
+import numpy as np
 import pytest
 
+from conftest import PAI_WINDOW, pai_quota_stream
 from repro.core import PolicyPrioritizer, make_cluster, make_policy
+from repro.core.cluster import ClusterState
+from repro.core.prioritizer import WindowFields
 from repro.core.types import Job
 from repro.sched import (EngineHooks, QuotaPrioritizer, SlaLanePrioritizer,
                          get_scenario, run_stream, wrap_tenancy)
+from repro.sched.engine import _PendingFieldIndex
 
 
 def _job(jid, *, user=0, vc=0, submit=0.0, runtime=100.0, gpus=1):
@@ -170,3 +175,134 @@ def test_wrap_tenancy_composition():
     assert isinstance(wrap_tenancy(base, vc_quotas={0: 0.5},
                                    enforce_quotas=False),
                       PolicyPrioritizer)
+
+
+# ------------------------------------- the gate against its plain reference ----
+
+
+def _list_gate(jobs, usage, quotas, total, order):
+    """The quota gate as a plain list partition: the reference the
+    columnar gate must equal for every input."""
+    over = {vc for vc, q in quotas.items() if usage.get(vc, 0) / total > q}
+    under = [i for i in order if jobs[i].vc not in over]
+    demoted = [i for i in order if jobs[i].vc in over]
+    return under + demoted
+
+
+class _Given:
+    """Base prioritizer that ranks every window with a given order."""
+
+    use_estimates = False
+
+    def __init__(self, order):
+        self.order = order
+
+    def rank(self, jobs, cluster, now):
+        return self.order
+
+    def rank_window(self, jobs, cluster, now, fields):
+        return self.order
+
+    def observe_finish(self, job):
+        pass
+
+
+ROWS = 4096
+QUOTAS = {0: 0.25, 1: 0.25, 2: 0.25, 3: 0.25}
+#: VC draws: the PAI deployment's skew over the four quota'd VCs, and
+#: uniform over five ids (one with no quota)
+VC_DRAWS = {"skewed": ([0, 1, 2, 3], [0.55, 0.25, 0.12, 0.08]),
+            "uniform": ([0, 1, 2, 3, 4], None)}
+
+
+def _window(source, vcs):
+    """``(jobs, fields)`` of a ranking window of ``ROWS`` rows: fields
+    built from the jobs, a ``take()`` row subset of a larger window, or the
+    engine's pending-field index sliced at ``ROWS``."""
+    jobs = [Job(job_id=i, user=i % 7, submit_time=float(i), runtime=60.0,
+                est_runtime=60.0, num_gpus=1 + i % 4, vc=int(v))
+            for i, v in enumerate(vcs)]
+    if source == "from_jobs":
+        return jobs[:ROWS], WindowFields.from_jobs(jobs[:ROWS])
+    if source == "take":
+        rows = sorted(np.random.default_rng(5).choice(
+            len(jobs), ROWS, replace=False).tolist())
+        return ([jobs[i] for i in rows],
+                WindowFields.from_jobs(jobs).take(rows))
+    index = _PendingFieldIndex()
+    for i, job in enumerate(jobs):
+        index.insert(i, job)
+    return jobs[:ROWS], index.window(ROWS)
+
+
+@pytest.mark.parametrize("form", ["list", "array"])
+@pytest.mark.parametrize("n_over", [0, 1, 2, 3])
+@pytest.mark.parametrize("draw", sorted(VC_DRAWS))
+@pytest.mark.parametrize("source", ["from_jobs", "take", "engine"])
+def test_quota_gate_equals_the_list_partition(source, draw, n_over, form):
+    """``rank_window`` and ``rank`` both give the plain partition's order,
+    on seeded windows with zero to three VCs over quota; with nothing to
+    demote ``rank_window`` hands the base order back untouched, and
+    ``rank`` gives the protocol's list."""
+    rng = np.random.default_rng(1000 * n_over + len(source) + len(draw))
+    ids, p = VC_DRAWS[draw]
+    jobs, fields = _window(source, rng.choice(ids, size=ROWS + 512, p=p))
+    cs = ClusterState(make_cluster("alibaba"))
+    total = cs.provisioned_gpu_totals()[0]
+    over = set(rng.choice(4, size=n_over, replace=False).tolist())
+    # one GPU above the quota's share, or exactly at it (not over)
+    usage = {vc: int(total * QUOTAS[vc]) + (vc in over) for vc in QUOTAS}
+    assert {vc for vc in QUOTAS if usage[vc] / total > QUOTAS[vc]} == over
+    perm = rng.permutation(len(jobs))
+    order = perm.tolist() if form == "list" else perm.astype(np.intp)
+    pri = QuotaPrioritizer(_Given(order), QUOTAS)
+    pri._usage = usage
+    want = _list_gate(jobs, usage, QUOTAS, total, perm.tolist())
+    got = pri.rank_window(jobs, cs, 0.0, fields)
+    listed = pri.rank(jobs, cs, 0.0)
+    assert [int(i) for i in got] == want
+    assert [int(i) for i in listed] == want
+    demoted = sum(jobs[i].vc in over for i in perm)
+    if demoted:
+        assert isinstance(got, np.ndarray) and got.dtype == np.intp
+        assert type(listed) is list
+        assert want != perm.tolist()
+    else:
+        assert got is order
+        assert listed is order if form == "list" else type(listed) is list
+
+
+@pytest.fixture(scope="module")
+def pai_fast():
+    """The PAI-shaped stream on the fast engine."""
+    return pai_quota_stream()
+
+
+def test_pai_stream_deep_and_gated(pai_fast):
+    """The stream exercises what the cell does: deep windows past the
+    actor's slots, and the gate demoting rows at most decisions, handing
+    the engine arrays."""
+    _, seen = pai_fast
+    assert max(rows for _, rows, _, _ in seen) == PAI_WINDOW
+    demoting = [form for form, _, demoted, _ in seen if demoted]
+    assert len(demoting) > len(seen) // 2
+    assert set(demoting) == {np.ndarray}
+
+
+def test_pai_stream_fast_engine_equals_reference_loop(pai_fast):
+    """The fast engine taking the gate's arrays schedules exactly as the
+    reference loop (``optimized=False``), which ranks through ``rank``."""
+    assert pai_quota_stream(optimized=False)[0] == pai_fast[0]
+
+
+def test_pai_stream_array_order_equals_list_order(pai_fast, monkeypatch):
+    """The gate's array order and the same order as a list give the same
+    schedule on the fast engine."""
+    gated = QuotaPrioritizer.rank_window
+
+    def as_list(self, *args):
+        return [int(i) for i in gated(self, *args)]
+    monkeypatch.setattr(QuotaPrioritizer, "rank_window", as_list)
+    sig, seen = pai_quota_stream()
+    assert {form for form, *_ in seen} == {list}
+    assert sig == pai_fast[0]

@@ -8,6 +8,7 @@ import os
 import jax
 import pytest
 
+from conftest import PAI_WINDOW, pai_quota_stream
 from repro.core.agent import PPOAgent
 from repro.core.cluster import _job_shape
 from repro.core.env import RLPrioritizer
@@ -87,6 +88,15 @@ def profiled(tmp_path_factory):
         jax.profiler.stop_trace()
     totals = {n: (c - before.get(n, (0, 0))[0], v - before.get(n, (0, 0))[1])
               for n, (c, v) in spans.traced_totals().items()}
+    events, start = _profile_events(log_dir, PARENTS)
+    return {"sig": sig, "obs": obs, "events": events, "start_ns": start,
+            "totals": totals, "windows": windows.windows}
+
+
+def _profile_events(log_dir, names):
+    """``[(name, start_ns, end_ns, metadata)]`` of the events named in
+    ``names`` in the profile under ``log_dir``, and the profile's start."""
+    from jax.profiler import ProfileData
     path = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
                                          "*.xplane.pb")))[-1]
     pd = ProfileData.from_file(path)
@@ -96,12 +106,11 @@ def profiled(tmp_path_factory):
             start = dict(plane.stats)["profile_start_time"]
         for line in plane.lines:
             for e in line.events:
-                if e.name in PARENTS:
+                if e.name in names:
                     events.append((e.name, e.start_ns,
                                    e.start_ns + e.duration_ns,
                                    dict(e.stats)))
-    return {"sig": sig, "obs": obs, "events": events, "start_ns": start,
-            "totals": totals, "windows": windows.windows}
+    return events, start
 
 
 def _parents(events):
@@ -245,3 +254,41 @@ def test_tracer_has_no_private_wall_origin():
     (ev,) = [e for e in doc["traceEvents"] if e.get("name") == "probe"]
     assert doc["otherData"]["clock_origin_ns"] == spans.ORIGIN_NS
     assert abs(spans.ORIGIN_NS + ev["ts"] * 1e3 - t0) < 1e6
+
+
+@pytest.fixture(scope="module")
+def quota_profiled(tmp_path_factory):
+    """The PAI-shaped deep stream under the quota gate, profiled: its
+    schedule, the plain partition's work per decision, and the profile's
+    ``engine.decide`` and ``rank.quota`` events."""
+    log_dir = str(tmp_path_factory.mktemp("quota_profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        sig, seen = pai_quota_stream()
+    finally:
+        jax.profiler.stop_trace()
+    events, _ = _profile_events(log_dir, {"engine.decide", "rank.quota"})
+    return {"sig": sig, "windows": [w[1:] for w in seen], "events": events}
+
+
+def test_quota_span_counts_rows_and_demotions(quota_profiled):
+    """One ``rank.quota`` span per ranking, inside its ``engine.decide``:
+    ``rows`` is the window, ``demoted`` the rows the plain partition puts
+    behind the rest, ``over`` the VCs over quota."""
+    events = quota_profiled["events"]
+    gates = sorted((ev for ev in events if ev[0] == "rank.quota"),
+                   key=lambda ev: ev[1])
+    got = [(m["rows"], m["demoted"], m["over"]) for *_, m in gates]
+    assert got == quota_profiled["windows"]
+    assert len(got) == quota_profiled["sig"][1][0]     # every decision
+    assert max(rows for rows, _, _ in got) == PAI_WINDOW
+    assert sum(1 for _, demoted, _ in got if demoted) > len(got) // 2
+    for (name, *_), parent in zip(events, _parents(events)):
+        if name == "rank.quota":
+            assert parent[0] == "engine.decide"
+
+
+def test_quota_spans_off_keep_the_schedule(quota_profiled):
+    assert pai_quota_stream()[0] == quota_profiled["sig"]

@@ -242,11 +242,11 @@ class PPOAgent:
                     self._traj["logp"].append(float(out["logp"]))
                     self._traj["value"].append(float(out["value"]))
                 return action, np.asarray(out["logits"])
-            order = greedy_step(self.params, jnp.asarray(ov),
-                                jnp.asarray(mask))
+            # host arrays go straight to the jitted call, and the order
+            # is read back once: the head index comes from that copy
+            order = np.asarray(greedy_step(self.params, ov, mask))
             logits = np.zeros(mask.shape, dtype=np.float32)
-            logits[np.asarray(order)] = -np.arange(len(mask),
-                                                   dtype=np.float32)
+            logits[order] = -np.arange(len(mask), dtype=np.float32)
             return int(order[0]), logits
 
     # -------------------------------------------------------------- update ----

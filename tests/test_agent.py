@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.agent import (PPOAgent, PPOConfig, actor_logits, greedy_step,
                               init_params, policy_step)
@@ -33,6 +34,50 @@ def test_greedy_is_argsort():
     order = np.asarray(greedy_step(params, jnp.asarray(ov), jnp.asarray(mask)))
     lg = np.asarray(actor_logits(params, jnp.asarray(ov), jnp.asarray(mask)))
     assert order[0] == int(np.argmax(lg))
+
+
+def _greedy_act_reference(params, ov, mask):
+    """Greedy ``act`` as separate steps: the rows and mask put on the
+    device, the order read back, the rank-encoded logits rebuilt on the
+    host.  Returns ``(order, action, logits)``."""
+    order = greedy_step(params, jnp.asarray(ov), jnp.asarray(mask))
+    logits = np.zeros(mask.shape, dtype=np.float32)
+    logits[np.asarray(order)] = -np.arange(len(mask), dtype=np.float32)
+    return np.asarray(order), int(order[0]), logits
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+@pytest.mark.parametrize("n", [1, 17, 255, 256])
+def test_greedy_act_matches_reference(n, ties):
+    agent = PPOAgent(PPOConfig(seed=4))
+    ov, cv, mask = _state(n, seed=n)
+    if ties:        # exact logit ties: rows drawn from three distinct ones
+        pick = np.random.default_rng(n).integers(0, 3, n)
+        ov[:n] = ov[pick % min(n, 3)]
+    order, want_action, want_logits = _greedy_act_reference(
+        agent.params, ov, mask)
+    action, logits = agent.act(ov, cv, mask, explore=False, record=False)
+    assert type(action) is int
+    assert action == want_action == int(order[0])
+    assert action < n
+    assert logits.dtype == np.float32 and logits.shape == mask.shape
+    np.testing.assert_array_equal(logits, want_logits)
+    assert agent.rollout_len == 0
+
+
+def test_greedy_act_reads_params_at_call_time():
+    """Callers swap ``params["actor"]`` after construction; the next call
+    ranks with the new weights."""
+    agent = PPOAgent(PPOConfig(seed=0))
+    other = init_params(PPOConfig(seed=7))
+    ov, cv, mask = _state(40, seed=1)
+    before = agent.act(ov, cv, mask, explore=False, record=False)[1]
+    agent.params["actor"] = other["actor"]
+    _, want_action, want = _greedy_act_reference(agent.params, ov, mask)
+    action, logits = agent.act(ov, cv, mask, explore=False, record=False)
+    assert action == want_action
+    np.testing.assert_array_equal(logits, want)
+    assert not np.array_equal(logits, before)
 
 
 def test_logp_matches_softmax():

@@ -2,16 +2,19 @@
 nothing and steers nothing, a ``jax.profiler`` session receives every
 span of the decision path with the right parent, and an attached bundle
 records the same spans on the profiler's clock."""
+import collections
 import glob
 import os
 
 import jax
+import numpy as np
 import pytest
 
 from conftest import PAI_WINDOW, pai_quota_stream
-from repro.core.agent import PPOAgent
+from repro.core.agent import PPOAgent, greedy_step
 from repro.core.cluster import _job_shape
 from repro.core.env import RLPrioritizer
+from repro.core.features import MAX_QUEUE_SIZE, OV_SIZE
 from repro.kernels.batch_score import BucketedScorer
 from repro.obs import Observability, spans, validate_trace
 from repro.obs.report import analyze
@@ -124,6 +127,47 @@ def _parents(events):
                 best = (pn, ps, pe, pm)
         out.append(best)
     return out
+
+
+def test_greedy_actor_is_one_dispatch_and_one_readback(tmp_path):
+    """Inside each greedy ``rank.actor`` span the host rows and mask go
+    straight to one ``greedy_step`` dispatch, and the order is read back
+    once: no Python-side transfer (``DevicePutWithSharding``) and no
+    indexing of the device array (``dynamic_slice``, ``squeeze``).  The
+    CPU backend nests ``PjitFunction`` events, so a bare call in the same
+    profile says how many one dispatch emits."""
+    agent = PPOAgent()
+    ov = np.zeros((MAX_QUEUE_SIZE, OV_SIZE), np.float32)
+    ov[:100] = np.random.default_rng(0).random((100, OV_SIZE))
+    mask = np.zeros((MAX_QUEUE_SIZE,), np.float32)
+    mask[:100] = 1.0
+    agent.act(ov, None, mask, explore=False, record=False)     # compiled
+    log_dir = str(tmp_path)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        for _ in range(3):
+            agent.act(ov, None, mask, explore=False, record=False)
+        np.asarray(greedy_step(agent.params, ov, mask))
+    finally:
+        jax.profiler.stop_trace()
+    dispatch = "PjitFunction(greedy_step)"
+    events, _ = _profile_events(log_dir, {
+        "rank.actor", dispatch, "PjitFunction(dynamic_slice)",
+        "PjitFunction(squeeze)", "DevicePutWithSharding"})
+    actors = [ev for ev in events if ev[0] == "rank.actor"]
+    assert len(actors) == 3
+
+    def inside(ev, outer):
+        return outer[1] <= ev[1] and ev[2] <= outer[2]
+    bare = [ev for ev in events if ev[0] == dispatch
+            and not any(inside(ev, a) for a in actors)]
+    assert bare
+    for a in actors:
+        got = collections.Counter(ev[0] for ev in events
+                                  if ev is not a and inside(ev, a))
+        assert got == {dispatch: len(bare)}
 
 
 def test_off_opens_no_annotation_and_keeps_schedule(baseline, monkeypatch):

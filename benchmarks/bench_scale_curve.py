@@ -98,15 +98,14 @@ class _DecisionTimer:
 
 
 def _stream(spec, fault_model, jobs: list[Job], queue_window: int, *,
-            compact: bool = False, deep_lookahead_k: int | None = None) -> dict:
+            compact: bool = False) -> dict:
     """Stream ``jobs`` through a fresh engine (1h ingest chunks, the
     bench_streaming driver) and report throughput + decision latency."""
     pri = _DecisionTimer(PolicyPrioritizer(make_policy("fcfs")))
     engine = SchedulerEngine(spec, pri, allocator="pack",
                              fault_model=fault_model,
                              queue_window=queue_window,
-                             completed_summary=compact,
-                             deep_lookahead_k=deep_lookahead_k)
+                             completed_summary=compact)
     jobs = [j.clone_pending() for j in jobs]
     t0 = time.perf_counter()
     feed = 0
@@ -304,7 +303,7 @@ def run(out: list[str] | None = None, smoke: bool = False) -> dict:
     # tiling never splits a tile: trace_jobs is a whole multiple upstream,
     # but guard the slice anyway so overrides can't strand arrivals
     r = _stream(base.spec, base.fault_model, trace, windows[0],
-                compact=True, deep_lookahead_k=4)
+                compact=True)
     assert r["completed"] == len(trace), (len(trace), r["completed"])
     trace_curve = {f"jobs{len(trace)}": r}
     print(f"{'trace/' + str(len(trace)):18s} {r['jobs_per_s']:8.0f} "

@@ -12,8 +12,9 @@ process, with no fallback:
 3. main stream: ``run_scenario("flash-crowd", 10_000 jobs)`` ranked by the
    greedy PPO actor with the deep-window scorer, MILP allocation, EASY
    backfill and the kernel runtime predictor;
-4. reference: the first 1,000 jobs of that stream through the optimized
-   and the naive engine give identical schedules, and the device actor's
+4. reference: the first 1,000 jobs of that stream through the engine
+   and the naive reference loop (``repro.sched.reference``) give
+   identical schedules, and the device actor's
    top-1 equals a float64 numpy actor on 200 sampled decisions of (3);
 5. training: PPO updates through ``repro.rl.StreamingTrainer``.
 
@@ -136,8 +137,8 @@ def main() -> int:
     from repro.predict import RuntimePredictor
     from repro.predict.predictor import PREDICT_FEATURES, QuantileMLP
     from repro.rl import StreamingConfig, StreamingTrainer
-    from repro.sched import (MultiHooks, get_scenario, run_scenario,
-                             run_stream, wrap_tenancy)
+    from repro.sched import (MultiHooks, get_scenario, reference_run,
+                             run_scenario, run_stream, wrap_tenancy)
 
     cache_dir = use_compile_cache()
     clock = CompileClock(jax)
@@ -242,33 +243,41 @@ def main() -> int:
         run = get_scenario("flash-crowd").build(NUM_JOBS, SEED)
         jobs = sorted(run.jobs, key=lambda j: (j.submit_time, j.job_id))
         jobs = jobs[:REF_JOBS]
-        out = {}
-        for optimized in (True, False):
-            pri = wrap_tenancy(
+
+        def prioritizer():
+            return wrap_tenancy(
                 RLPrioritizer(agent, explore=False,
                               deep_scorer=BucketedScorer(actor)),
                 run.sla_users, run.vc_quotas)
-            sr = run_stream(run.spec, [j.clone_pending() for j in jobs], pri,
-                            allocator="milp", backfill=True,
-                            queue_window=QUEUE_WINDOW,
-                            fault_model=run.fault_model, chunked_submit=True,
-                            optimized=optimized,
-                            predictor=RuntimePredictor(use_kernel=True))
-            eng = sr.engine
-            out[optimized] = (
+        kw = dict(allocator="milp", backfill=True, queue_window=QUEUE_WINDOW,
+                  fault_model=run.fault_model)
+        # the whole stream up front on both sides: the predictor caches
+        # each job's features when it is submitted, so chunked submission
+        # is a different (assisted) schedule
+        fast = run_stream(run.spec, [j.clone_pending() for j in jobs],
+                          prioritizer(), chunked_submit=False,
+                          predictor=RuntimePredictor(use_kernel=True),
+                          **kw).engine
+        naive = reference_run(run.spec, [j.clone_pending() for j in jobs],
+                              prioritizer(),
+                              predictor=RuntimePredictor(use_kernel=True),
+                              **kw)
+        out = {}
+        for name, eng in (("engine", fast), ("reference", naive)):
+            out[name] = (
                 sorted((j.job_id, j.start_time, j.finish_time, j.restarts,
                         tuple(sorted((j.placement or {}).items())))
-                       for j in sr.batch.jobs),
+                       for j in eng.completed),
                 (eng.decisions, eng.milp_calls, eng.milp_fallbacks,
                  eng.backfills))
-            print(f"  optimized={optimized} jobs={len(sr.batch.jobs)} "
+            print(f"  {name} jobs={len(eng.completed)} "
                   f"counters(decisions, milp, fallback, backfills)="
-                  f"{out[optimized][1]}", flush=True)
-        check(len(out[True][0]) == REF_JOBS, "reference stream incomplete")
-        check(out[True][0] == out[False][0],
-              "optimized and naive job tuples differ")
-        check(out[True][1] == out[False][1],
-              "optimized and naive counters differ")
+                  f"{out[name][1]}", flush=True)
+        check(len(out["engine"][0]) == REF_JOBS, "reference stream incomplete")
+        check(out["engine"][0] == out["reference"][0],
+              "engine and reference job tuples differ")
+        check(out["engine"][1] == out["reference"][1],
+              "engine and reference counters differ")
 
         agree, gaps = 0, []
         for ov, mask, action in samples:

@@ -41,7 +41,6 @@ class Simulator:
         straggler_migration: bool = True,
         max_sim_time: float = 90 * 86400.0,
         queue_window: int | None = None,   # None = engine default (2560)
-        optimized: bool = True,            # False = naive reference engine
     ):
         self.spec = spec
         self.allocator = allocator
@@ -51,7 +50,6 @@ class Simulator:
         self.straggler_migration = straggler_migration
         self.max_sim_time = max_sim_time
         self.queue_window = queue_window
-        self.optimized = optimized
 
     def make_engine(self, prioritizer: Prioritizer) -> "SchedulerEngine":
         """A fresh streaming engine configured like this simulator."""
@@ -64,7 +62,6 @@ class Simulator:
             fault_model=self.fault_model,
             straggler_migration=self.straggler_migration,
             max_sim_time=self.max_sim_time, queue_window=self.queue_window,
-            optimized=self.optimized,
         )
 
     # ------------------------------------------------------------------ run ----

@@ -120,8 +120,7 @@ class FederatedScheduler:
     must never share prioritizer state (a ``QuotaPrioritizer``'s usage
     tracking is per engine, so the factory is called once per cluster).
     ``QuotaPrioritizer`` instances are wired exactly like ``run_stream``
-    does: attached as the engine's hook (incremental usage) and handed the
-    engine reference for the recompute reference path.
+    does: usage reset and attached as the engine's hook.
     """
 
     def __init__(
@@ -139,7 +138,6 @@ class FederatedScheduler:
         telemetry_window: float = 6 * 3600.0,
         sample_interval: float = 600.0,
         router_seed: int = 0,
-        optimized: bool = True,
         autoscalers: Sequence | None = None,
         migration=None,
         obs=None,
@@ -196,7 +194,7 @@ class FederatedScheduler:
                 hooks.extend(mobs.hooks())
             if self.predictors[i] is not None:
                 hooks.append(self.predictors[i])
-            if isinstance(pri, QuotaPrioritizer) and pri.incremental:
+            if isinstance(pri, QuotaPrioritizer):
                 pri.reset_usage()
                 hooks.append(pri)
             # one MultiHooks per engine: duck-typed observers get the full
@@ -205,10 +203,8 @@ class FederatedScheduler:
             engine = SchedulerEngine(
                 spec, pri, allocator=allocator, backfill=backfill,
                 lookahead_k=lookahead_k, fault_model=fms[i],
-                queue_window=queue_window, hooks=hooks, optimized=optimized,
+                queue_window=queue_window, hooks=hooks,
                 predictor=self.predictors[i])
-            if isinstance(pri, QuotaPrioritizer):
-                pri.engine = engine
             self.engines.append(engine)
             self.telemetries.append(tel)
         self.infos = [ClusterInfo.from_spec(i, spec)
@@ -624,7 +620,6 @@ def run_fleet(
     telemetry_window: float = 6 * 3600.0,
     sample_interval: float = 600.0,
     router_seed: int = 0,
-    optimized: bool = True,
     autoscaler_factory: Callable | None = None,
     migration=None,
     chaos=None,
@@ -694,7 +689,7 @@ def run_fleet(
         allocator=allocator, backfill=backfill,
         fault_models=run.fault_models, queue_window=queue_window,
         telemetry_window=telemetry_window, sample_interval=sample_interval,
-        router_seed=router_seed, optimized=optimized,
+        router_seed=router_seed,
         autoscalers=autoscalers, migration=migration, obs=obs,
         parallel=parallel, predictors=predictors)
 

@@ -3,7 +3,8 @@ jobs were skipped.
 
 The engine's gated audit stream (``on_decision_audit`` /
 ``on_window_blocked``, see ``repro.sched.engine``) emits one record per
-scheduling decision on the optimized path:
+scheduling decision of the engine's decision loop (the reference loop in
+``repro.sched.reference`` emits none):
 
 .. code-block:: python
 

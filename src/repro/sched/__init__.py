@@ -9,6 +9,7 @@ from repro.sched.scenarios import (SCENARIOS, Scenario, ScenarioRun,
 from repro.sched.service import (QuotaPrioritizer, SlaLanePrioritizer,
                                  StreamResult, run_scenario, run_stream,
                                  wrap_tenancy)
+from repro.sched.reference import ReferenceEngine, reference_run
 from repro.sched.telemetry import (RollingTelemetry, TelemetrySample,
                                    jain_index)
 
@@ -17,6 +18,7 @@ __all__ = [
     "PolicyPrioritizer", "Prioritizer", "SchedulerEngine", "SCENARIOS",
     "Scenario", "ScenarioRun", "get_scenario", "list_scenarios", "register",
     "QuotaPrioritizer", "SlaLanePrioritizer", "StreamResult", "run_scenario",
-    "run_stream", "wrap_tenancy", "RollingTelemetry", "TelemetrySample",
+    "run_stream", "wrap_tenancy", "ReferenceEngine", "reference_run",
+    "RollingTelemetry", "TelemetrySample",
     "jain_index",
 ]

@@ -26,14 +26,13 @@ the schedule.
 
 Decision-loop complexity
 ------------------------
-The default (``optimized=True``) hot path keeps per-event cost near
-O(log n) amortized in the pending-queue depth n:
+The decision loop keeps per-event cost near O(log n) amortized in the
+pending-queue depth n:
 
 - ``pending`` is an **indexed queue**: a list maintained sorted by
   ``(submit_time, job_id)`` via ``bisect`` — insertion is O(log n)
   comparisons (plus a C-level memmove), window extraction is an O(window)
   slice, and removal locates the job by bisection instead of a linear scan.
-  The naive path re-sorted the whole list and ``.remove()``'d per decision.
 - The cluster carries a **version counter** (see ``repro.core.cluster``)
   bumped on allocate/release/fail_node/recover_node; per-SKU free-GPU
   tallies and per-job-shape ``can_schedule_now`` / ``candidate_ways``
@@ -46,9 +45,11 @@ O(log n) amortized in the pending-queue depth n:
 - ``PolicyPrioritizer`` scores the window with one ``score_batch`` call
   (numpy, bit-identical to the scalar loop) instead of a Python loop.
 
-``optimized=False`` retains the seed's naive loop — re-sort + linear scans,
-no caches, scalar scoring — as the reference for differential equivalence
-tests; both paths must produce bit-identical schedules.
+The seed's naive loop — re-sort + linear scans, no caches, scalar scoring —
+lives in ``repro.sched.reference.ReferenceEngine``, the reference for the
+differential equivalence tests; both must produce bit-identical schedules.
+A saved state names its loop (``optimized``), and ``load_state`` restores
+the matching class.
 """
 from __future__ import annotations
 
@@ -190,7 +191,7 @@ class EngineHooks:
                     engine: "SchedulerEngine") -> None:
         """One prioritizer decision: ``jobs`` is the ranking window handed
         to the prioritizer, ``order`` its returned permutation (index 0 =
-        scheduled first).  Fired on both engine paths right after ranking —
+        scheduled first).  Fired on every engine loop right after ranking —
         this is how the streaming RL episode cutter (``repro.rl``) aligns
         rewards with recorded policy steps.  Observational only."""
         ...
@@ -393,10 +394,13 @@ class SchedulerEngine:
     across calls, so a driver can interleave submission and stepping
     indefinitely without restarting the cluster.
 
-    ``optimized`` selects the indexed-queue + feasibility-cache hot path
-    (default); ``optimized=False`` runs the retained naive reference loop.
-    Both produce bit-identical schedules.
+    ``repro.sched.reference.ReferenceEngine`` runs the seed's naive loop
+    over the same state; both produce bit-identical schedules.
     """
+
+    #: which decision loop runs; travels in the saved state so
+    #: ``load_state`` can restore the reference loop as itself
+    optimized = True
 
     def __init__(
         self,
@@ -411,12 +415,9 @@ class SchedulerEngine:
         max_sim_time: float = 90 * 86400.0,
         queue_window: int | None = None,   # None = DEFAULT_QUEUE_WINDOW
         hooks: Iterable[EngineHooks] = (),
-        optimized: bool = True,
         degradation=None,                  # duck-typed DegradationPolicy
         completed_summary: bool = False,
         completed_keep: int = 1024,
-        deep_lookahead_k: int | None = None,
-        deep_queue_threshold: int = 4096,
         predictor=None,                    # duck-typed RuntimePredictor
     ):
         self.spec = spec
@@ -430,7 +431,6 @@ class SchedulerEngine:
         self.queue_window = (queue_window if queue_window is not None
                              else DEFAULT_QUEUE_WINDOW)
         self.hooks: list[EngineHooks] = list(hooks)
-        self.optimized = optimized
         #: control-plane degradation ladder (see ``repro.chaos``); the
         #: engine duck-types the policy so ``repro.sched`` never imports
         #: ``repro.chaos``.  ``None`` (the default) never reads the
@@ -449,19 +449,18 @@ class SchedulerEngine:
             if bind is not None:
                 bind(self)
 
-        self.cluster = ClusterState(spec, cache=optimized)
+        self.cluster = ClusterState(spec, cache=True)
         self._seq = itertools.count()
         self._events: list[tuple[float, int, str, object]] = []
-        #: pending queue; in optimized mode kept sorted by (submit_time,
-        #: job_id) at all times (indexed queue), in naive mode re-sorted
-        #: inside ``_try_schedule`` exactly like the seed loop
+        #: pending queue, kept sorted by (submit_time, job_id) at all
+        #: times (indexed queue)
         self.pending: list[Job] = []
         # job_id -> [job, placement, start, finish, speed]
         self.running: dict[int, list] = {}
         #: finish-time-ordered index over `running`: sorted (finish, job_id)
         #: pairs maintained on start/finish/kill/rescale so backfill
         #: reservations (`_earliest_start`) iterate it directly instead of
-        #: re-sorting the running set per call (optimized mode only)
+        #: re-sorting the running set per call
         self._finish_index: list[tuple[float, int]] = []
         self.remaining: dict[int, float] = {}
         self.completed: list[Job] = []
@@ -478,12 +477,6 @@ class SchedulerEngine:
         self._sum_jct = 0.0
         self._sum_wait = 0.0
         self._max_finish = -math.inf
-        #: opt-in deep-queue lookahead shrink: when the pending queue is
-        #: deeper than ``deep_queue_threshold``, MILP lookahead is cut to
-        #: ``deep_lookahead_k`` jobs (a smaller model per solve).  The
-        #: default (None) never changes the lookahead — pinned.
-        self.deep_lookahead_k = deep_lookahead_k
-        self.deep_queue_threshold = deep_queue_threshold
         self.gpu_seconds = 0.0
         self.decisions = 0
         self.milp_calls = 0
@@ -525,7 +518,7 @@ class SchedulerEngine:
         self.submitted = 0
         self._injector: FaultInjector | None = None
         self._scratch: ClusterState | None = None   # _earliest_start reuse
-        self._pindex = _PendingFieldIndex() if optimized else None
+        self._pindex = _PendingFieldIndex()
         self._rank_window = getattr(prioritizer, "rank_window", None)
         #: version-keyed negative placement memo for the backfill scan:
         #: shape ids proven unplaceable at ``_neg_ver`` (== cluster.version).
@@ -635,25 +628,19 @@ class SchedulerEngine:
 
     # ------------------------------------------------------ pending queue ----
     def _push_pending(self, job: Job) -> None:
-        if self.optimized:
-            idx = bisect.bisect_right(self.pending, _pending_key(job),
-                                      key=_pending_key)
-            self.pending.insert(idx, job)
-            self._pindex.insert(idx, job)
-        else:
-            self.pending.append(job)
+        idx = bisect.bisect_right(self.pending, _pending_key(job),
+                                  key=_pending_key)
+        self.pending.insert(idx, job)
+        self._pindex.insert(idx, job)
 
     def _remove_pending(self, job: Job) -> None:
-        if self.optimized:
-            idx = bisect.bisect_left(self.pending, _pending_key(job),
-                                     key=_pending_key)
-            # job_ids are unique, so bisection lands exactly on `job`
-            if not (idx < len(self.pending) and self.pending[idx] is job):
-                idx = self.pending.index(job)   # defensive: keep index in sync
-            del self.pending[idx]
-            self._pindex.remove(idx)
-            return
-        self.pending.remove(job)
+        idx = bisect.bisect_left(self.pending, _pending_key(job),
+                                 key=_pending_key)
+        # job_ids are unique, so bisection lands exactly on `job`
+        if not (idx < len(self.pending) and self.pending[idx] is job):
+            idx = self.pending.index(job)   # defensive: keep index in sync
+        del self.pending[idx]
+        self._pindex.remove(idx)
 
     # ------------------------------------------------- finish-time index ----
     def _finish_index_remove(self, finish: float, jid: int) -> None:
@@ -852,8 +839,7 @@ class SchedulerEngine:
         transition(job, JobState.RUNNING)
         job.placement = placement
         self.running[job.job_id] = [job, placement, self.now, finish, speed]
-        if self.optimized:
-            bisect.insort(self._finish_index, (finish, job.job_id))
+        bisect.insort(self._finish_index, (finish, job.job_id))
         heapq.heappush(self._events,
                        (finish, next(self._seq), "finish", job.job_id))
         for h in self.hooks:
@@ -955,8 +941,6 @@ class SchedulerEngine:
 
     # -- EASY backfill: earliest start for the reserved job -----------------
     def _earliest_start(self, job: Job) -> float:
-        if not self.optimized:
-            return self._earliest_start_naive(job)
         if self._scratch is None or \
                 len(self._scratch.total_gpus) != len(self.cluster.total_gpus):
             # rebuild after add_node grew the cluster (spec reflects it)
@@ -973,26 +957,6 @@ class SchedulerEngine:
         for fin, jid in self._finish_index:
             rec = self.running[jid]
             sim.release(rec[0], rec[1])
-            if sim.find_placement(job, "pack") is not None:
-                return fin
-        return float("inf")
-
-    def _earliest_start_naive(self, job: Job) -> float:
-        """Seed implementation: fresh ClusterState (four array allocations)
-        per reservation.  Retained as the differential reference."""
-        cluster = self.cluster
-        sim = ClusterState(self.spec)
-        sim.free_gpus = cluster.free_gpus.copy()
-        sim.free_cpus = cluster.free_cpus.copy()
-        sim.free_mem = cluster.free_mem.copy()
-        sim.node_down = cluster.node_down.copy()
-        sim.cordoned = cluster.cordoned.copy()
-        sim.retired = cluster.retired.copy()
-        if sim.find_placement(job, "pack") is not None:
-            return self.now
-        for jid, (rj, pl, st, fin, sp) in sorted(self.running.items(),
-                                                 key=lambda kv: kv[1][3]):
-            sim.release(rj, pl)
             if sim.find_placement(job, "pack") is not None:
                 return fin
         return float("inf")
@@ -1016,8 +980,7 @@ class SchedulerEngine:
         job, placement, st, fin, speed = self.running.pop(jid)
         if self._bf_deadlines:
             self._bf_deadlines.pop(jid, None)
-        if self.optimized:
-            self._finish_index_remove(fin, jid)
+        self._finish_index_remove(fin, jid)
         self.cluster.release(job, placement)
         elapsed = max(0.0, self.now - st)
         work_done = elapsed * speed
@@ -1274,8 +1237,7 @@ class SchedulerEngine:
         job, placement, st, fin, speed = rec
         if self._bf_deadlines:
             self._bf_deadlines.pop(jid, None)
-        if self.optimized:
-            self._finish_index_remove(fin, jid)
+        self._finish_index_remove(fin, jid)
         self.cluster.release(job, placement)
         job.finish_time = self.now
         transition(job, JobState.COMPLETED)
@@ -1329,39 +1291,19 @@ class SchedulerEngine:
             left = max(fin - self.now, 0.0) * speed / new_speed
             rec[3] = self.now + left
             rec[4] = new_speed
-            if self.optimized:
-                self._finish_index_remove(fin, jid)
-                bisect.insort(self._finish_index, (rec[3], jid))
+            self._finish_index_remove(fin, jid)
+            bisect.insort(self._finish_index, (rec[3], jid))
             heapq.heappush(self._events,
                            (rec[3], next(self._seq), "finish", jid))
 
     # ------------------------------------------------------ schedulability ----
-    def _any_schedulable(self, queue: list[Job]) -> bool:
-        """Same boolean as ``any(can_schedule_now(j) for j in queue)`` but
-        with a cheap necessary-condition prefilter (enough free GPUs of the
-        requested SKU on up nodes) so saturated clusters skip the expensive
-        placement search for the whole window.  On the optimized path the
-        per-SKU tallies and per-shape feasibility come from the cluster's
-        version-keyed cache, so repeat scans cost one dict hit per job."""
-        if not self.optimized:
-            return self._any_schedulable_naive(queue)
-        cluster = self.cluster
-        free_any, free_by_type = cluster.free_gpu_tallies()
-        if free_any == 0:
-            return False
-        can = cluster.can_schedule_now
-        for j in queue:
-            avail = free_any if j.gpu_type == "any" \
-                else free_by_type.get(j.gpu_type, 0)
-            if avail >= j.num_gpus and can(j):
-                return True
-        return False
-
     def _any_schedulable_window(self, bound: int) -> bool:
-        """``_any_schedulable`` over the first ``bound`` pending jobs
-        *without* materializing the window slice — blocked passes on deep
-        queues (the common case under saturation) pay a bounded scan over
-        the already-sorted pending list and nothing else."""
+        """Can any of the first ``bound`` pending jobs start now?  A cheap
+        necessary-condition prefilter (enough free GPUs of the requested
+        SKU on up nodes) runs before the cached placement search, and the
+        window slice is never materialized — blocked passes on deep queues
+        (the common case under saturation) pay a bounded scan over the
+        already-sorted pending list and nothing else."""
         cluster = self.cluster
         free_any, free_by_type = cluster.free_gpu_tallies()
         if free_any == 0:
@@ -1373,23 +1315,6 @@ class SchedulerEngine:
             avail = free_any if j.gpu_type == "any" \
                 else free_by_type.get(j.gpu_type, 0)
             if avail >= j.num_gpus and can(j):
-                return True
-        return False
-
-    def _any_schedulable_naive(self, queue: list[Job]) -> bool:
-        cluster = self.cluster
-        up = cluster.placeable_mask()
-        free_any = int(cluster.free_gpus[up].sum())
-        if free_any == 0:
-            return False
-        free_by_type: dict[str, int] = {}
-        for i, t in enumerate(cluster.gpu_types):
-            if up[i]:
-                free_by_type[t] = free_by_type.get(t, 0) + int(cluster.free_gpus[i])
-        for j in queue:
-            avail = free_any if j.gpu_type == "any" \
-                else free_by_type.get(j.gpu_type, 0)
-            if avail >= j.num_gpus and cluster.can_schedule_now(j):
                 return True
         return False
 
@@ -1487,8 +1412,6 @@ class SchedulerEngine:
             h.on_decision_audit(rec)
 
     def _schedule_pass(self) -> None:
-        if not self.optimized:
-            return self._try_schedule_naive()
         cluster, prioritizer = self.cluster, self.prioritizer
         rank_window = self._rank_window
         #: with audit observers attached (repro.obs) every decision builds
@@ -1539,11 +1462,7 @@ class SchedulerEngine:
                            "rank_wall_s": (clock_ns() - t_rank) * 1e-9,
                            "top_job": top.job_id, "placed": False,
                            "alloc": "none", "skips": {}, "backfills": 0}
-                k_look = self.lookahead_k
-                if (self.deep_lookahead_k is not None
-                        and len(self.pending) > self.deep_queue_threshold):
-                    k_look = min(k_look, self.deep_lookahead_k)
-                rest = [queue[i] for i in order[1:1 + k_look]]
+                rest = [queue[i] for i in order[1:1 + self.lookahead_k]]
                 durations = self._lookahead_durations(rest)
                 calls0, fb0 = self.milp_calls, self.milp_fallbacks
                 placement = self._alloc_for(top, rest, durations)
@@ -1629,8 +1548,8 @@ class SchedulerEngine:
                             continue
                         # free-tally prefilter: a per-SKU shortfall is a proof of
                         # infeasibility (the same necessary condition
-                        # `_any_schedulable` uses), so `_alloc_impl` would return
-                        # None — skip the candidate-ways probe entirely
+                        # `_any_schedulable_window` uses), so `_alloc_impl`
+                        # would return None — skip the candidate-ways probe
                         avail = free_any if cand.gpu_type == "any" \
                             else free_by_type.get(cand.gpu_type, 0)
                         if avail < cand.num_gpus:
@@ -1691,7 +1610,6 @@ class SchedulerEngine:
         "_deg_window_wall", "_deg_fcfs_until",
         "completed_summary", "completed_count", "completed_ring",
         "_sum_jct", "_sum_wait", "_max_finish",
-        "deep_lookahead_k", "deep_queue_threshold",
         "predictor", "bf_reservations", "bf_overruns", "_bf_deadlines",
         "_bf_overrun_jobs",
     )
@@ -1704,49 +1622,42 @@ class SchedulerEngine:
 
         One ``pickle.dumps`` over the whole attribute dict keeps shared
         ``Job`` identity intact (a job referenced from both the pending
-        queue and a queued arrival event restores as one object).  A
-        prioritizer back-reference to the engine (``QuotaPrioritizer``'s
-        differential path) is detached for the dump and restored after."""
-        pri = self.prioritizer
-        had_ref = hasattr(pri, "engine")
-        ref = getattr(pri, "engine", None)
-        if had_ref:
-            pri.engine = None
-        try:
-            state = {name: getattr(self, name) for name in self._STATE_ATTRS}
-            return pickle.dumps(state, protocol=pickle.HIGHEST_PROTOCOL)
-        finally:
-            if had_ref:
-                pri.engine = ref
+        queue and a queued arrival event restores as one object)."""
+        state = {name: getattr(self, name) for name in self._STATE_ATTRS}
+        return pickle.dumps(state, protocol=pickle.HIGHEST_PROTOCOL)
 
     @classmethod
     def load_state(cls, blob: bytes,
                    hooks: Iterable[EngineHooks] = ()) -> "SchedulerEngine":
-        """Restore an engine from :meth:`save_state`.  ``hooks`` re-attaches
-        the restoring driver's observers (telemetry, RL recorders); an
-        incremental ``QuotaPrioritizer`` travelling inside the blob is
-        re-appended as a hook automatically, its pickled usage intact."""
+        """Restore an engine from :meth:`save_state`.  A state whose
+        ``optimized`` is False restores as the reference loop
+        (``repro.sched.reference.ReferenceEngine``).  ``hooks`` re-attaches
+        the restoring caller's observers (telemetry, RL recorders); a
+        prioritizer that is itself a hook (the ``QuotaPrioritizer`` gate)
+        is re-appended automatically, its pickled usage intact."""
         state = pickle.loads(blob)
-        eng = cls.__new__(cls)
+        fast = state.pop("optimized", True)
+        if fast:
+            eng = SchedulerEngine.__new__(SchedulerEngine)
+        else:
+            # imported here: the reference module builds on this one
+            from repro.sched.reference import ReferenceEngine
+            eng = ReferenceEngine.__new__(ReferenceEngine)
         for name, value in state.items():
             setattr(eng, name, value)
         eng.hooks = list(hooks)
         # derived caches: rebuilt, never pickled
         eng._scratch = None
-        if eng.optimized:
+        eng._pindex = None
+        if fast:
             eng._pindex = _PendingFieldIndex()
             for idx, job in enumerate(eng.pending):
                 eng._pindex.insert(idx, job)
-        else:
-            eng._pindex = None
         eng._rank_window = getattr(eng.prioritizer, "rank_window", None)
         eng._neg_shapes = set()
         eng._neg_ver = -1
         pri = eng.prioritizer
-        if hasattr(pri, "engine"):
-            pri.engine = eng
-        if isinstance(pri, EngineHooks) and getattr(pri, "incremental",
-                                                    False):
+        if isinstance(pri, EngineHooks) and pri not in eng.hooks:
             eng.hooks.append(pri)
         # a predictor travelling inside the blob (trained weights, MAPE
         # state) is rebound and re-attached as a hook so training resumes
@@ -1759,63 +1670,3 @@ class SchedulerEngine:
                 eng.hooks.append(pred)
         eng._rebuild_hook_dispatch()
         return eng
-
-    def _try_schedule_naive(self) -> None:
-        """Seed decision loop: full re-sort + linear `.remove()` per decision.
-        Retained verbatim as the reference for differential equivalence."""
-        cluster, prioritizer = self.cluster, self.prioritizer
-        while self.pending:
-            self.pending.sort(key=lambda j: (j.submit_time, j.job_id))
-            queue = self.pending[: self.queue_window]
-            if not self._any_schedulable(queue):
-                return
-            if self._fcfs_degraded():
-                order = list(range(len(queue)))
-            else:
-                order = prioritizer.rank(queue, cluster, self.now)
-            self.decisions += 1
-            if self.hooks:
-                self._fire_decision(queue, order)
-            top = queue[order[0]]
-            rest = [queue[i] for i in order[1:1 + self.lookahead_k]]
-            placement = self._alloc_for(top, rest,
-                                        self._lookahead_durations(rest))
-            if placement is not None:
-                self.pending.remove(top)
-                self._start_job(top, placement)
-                continue
-            if not self.backfill:
-                return
-            # EASY backfill under reservation for `top`
-            t_res = self._earliest_start(top)
-            progressed = False
-            pred = self._predict_assist()
-            for i in order[1:]:
-                cand = queue[i]
-                if cand.state != JobState.PENDING or cand is top:
-                    continue
-                if pred is not None:
-                    if cand.job_id in self._bf_overrun_jobs:
-                        continue
-                    rt = max(float(pred.reserve_runtime(cand, self)), 1.0)
-                else:
-                    rt = self._est_rt(cand)
-                if self.now + rt > t_res:
-                    continue
-                pl = self._alloc_for(cand, [])
-                if pl is not None:
-                    self.pending.remove(cand)
-                    self._start_job(cand, pl)
-                    self.backfills += 1
-                    progressed = True
-                    if pred is not None and t_res < math.inf:
-                        self.bf_reservations += 1
-                        self._bf_deadlines[cand.job_id] = t_res
-                        note = getattr(pred, "note_reservation", None)
-                        if note is not None:
-                            note(t_res - (self.now + rt))
-            if not progressed:
-                return
-            # after backfills the reserved job may now fit; loop again
-            if not cluster.can_schedule_now(top):
-                return

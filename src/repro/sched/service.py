@@ -105,19 +105,14 @@ class QuotaPrioritizer(EngineHooks):
 
     Per-VC running GPU usage is maintained **incrementally**: the driver
     attaches the prioritizer as an engine hook, so every job start / finish /
-    fault-requeue transition updates one dict entry (O(1)) instead of the
-    former O(running) recompute on every ``rank`` call.
-    ``incremental=False`` retains that recompute (reading
-    ``self.engine.running``) as the differential reference path — both must
-    gate identically."""
+    fault-requeue transition updates one dict entry (O(1)) instead of an
+    O(running) recompute on every ``rank`` call (the tests hold the usage
+    to that recompute at every decision)."""
 
-    def __init__(self, base: Prioritizer, quotas: dict[int, float],
-                 incremental: bool = True):
+    def __init__(self, base: Prioritizer, quotas: dict[int, float]):
         self.base = base
         self.quotas = quotas
         self.use_estimates = base.use_estimates
-        self.incremental = incremental
-        self.engine: SchedulerEngine | None = None   # attached by the driver
         self._usage: dict[int, int] = {}   # vc -> running GPUs (hook-fed)
         self._base_rank_window = getattr(base, "rank_window", None)
 
@@ -143,22 +138,13 @@ class QuotaPrioritizer(EngineHooks):
         fresh, idle engine so a reused prioritizer can't carry stale state)."""
         self._usage.clear()
 
-    def _vc_usage(self) -> dict[int, int]:
-        if self.incremental:
-            return self._usage
-        used: dict[int, int] = {}
-        if self.engine is not None:
-            for job, *_ in self.engine.running.values():
-                used[job.vc] = used.get(job.vc, 0) + job.num_gpus
-        return used
-
     def _gate(self, vc, cluster, order):
         """Stable partition of the base ``order`` over the window's VC
         column ``vc``: rows of VCs over quota fall behind every other row,
         each side keeping its base order.  With nothing to demote the base
         order comes back untouched; otherwise an ``np.intp`` array."""
         with span("rank.quota", rows=len(order)) as sp:
-            used = self._vc_usage()
+            used = self._usage
             # provisioned (non-retired) capacity: VC shares must track
             # elastic cluster size, and equal the raw total whenever
             # autoscaling is off
@@ -231,7 +217,6 @@ def run_stream(
     telemetry: RollingTelemetry | None = None,
     chunked_submit: bool = False,
     hooks: tuple[EngineHooks, ...] = (),
-    optimized: bool = True,
     on_window: "Callable[[SchedulerEngine, float, int], None] | None" = None,
     autoscaler=None,
     preemption=None,
@@ -285,7 +270,7 @@ def run_stream(
     (``assist=False``) are pinned bit-identical (tested).
 
     All observers — user ``hooks``, telemetry, obs sinks, and the
-    incremental quota gate — are composed through one ``MultiHooks``, so a
+    quota gate — are composed through one ``MultiHooks``, so a
     duck-typed partial hook object receives exactly the events it defines
     (the full ``EngineHooks`` surface, ``on_preempt`` / ``on_resume`` /
     ``on_decision`` / ``on_tick`` included) and a raising observer is
@@ -305,7 +290,7 @@ def run_stream(
         # hook-trained: on_submit caches features, on_finish does one SGD
         # step — shadow (assist=False) predictors observe without steering
         children.append(predictor)
-    if isinstance(prioritizer, QuotaPrioritizer) and prioritizer.incremental:
+    if isinstance(prioritizer, QuotaPrioritizer):
         # hook-fed per-VC usage: the engine starts idle, so start from zero
         prioritizer.reset_usage()
         children.append(prioritizer)
@@ -313,10 +298,8 @@ def run_stream(
     engine = SchedulerEngine(
         spec, prioritizer, allocator=allocator, backfill=backfill,
         lookahead_k=lookahead_k, fault_model=fault_model,
-        queue_window=queue_window, hooks=all_hooks, optimized=optimized,
+        queue_window=queue_window, hooks=all_hooks,
         degradation=degradation, predictor=predictor)
-    if isinstance(prioritizer, QuotaPrioritizer):
-        prioritizer.engine = engine
 
     with (obs.recording() if obs is not None
           else contextlib.nullcontext()):

@@ -72,7 +72,7 @@ def helios_cluster():
 PAI_WINDOW = 320
 
 
-def pai_quota_stream(*, optimized=True):
+def pai_quota_stream(*, reference=False):
     """A small stream shaped like the ``pai-quota`` cell: the
     ``multi-tenant`` scenario (the Alibaba PAI slice over T4, P100 and
     V100; four VCs with 55/25/12/8% of the demand and 25% quotas) with 360
@@ -83,13 +83,15 @@ def pai_quota_stream(*, optimized=True):
     decision, MILP and backfill counters) and, per decision, what the
     plain list partition does there: the type of the order the engine was
     handed, the window's rows, how many of them belong to VCs over quota
-    (the rows it demotes) and how many VCs are over quota."""
+    (the rows it demotes) and how many VCs are over quota.  With
+    ``reference`` the stream runs on the reference loop
+    (``repro.sched.reference_run``) instead of ``run_stream``."""
     from repro.core.agent import PPOAgent
     from repro.core.env import RLPrioritizer
     from repro.kernels.batch_score import BucketedScorer
     from repro.predict import RuntimePredictor
-    from repro.sched import (EngineHooks, get_scenario, run_stream,
-                             wrap_tenancy)
+    from repro.sched import (EngineHooks, get_scenario, reference_run,
+                             run_stream, wrap_tenancy)
 
     class Partitions(EngineHooks):
         def __init__(self):
@@ -112,11 +114,16 @@ def pai_quota_stream(*, optimized=True):
                       deep_scorer=BucketedScorer(agent.params["actor"])),
         vc_quotas=run.vc_quotas)
     seen = Partitions()
-    res = run_stream(run.spec, [j.clone_pending() for j in run.jobs], pri,
-                     queue_window=PAI_WINDOW, chunked_submit=True,
-                     predictor=RuntimePredictor(assist=False),
-                     optimized=optimized, hooks=(seen,))
-    eng = res.engine
+    jobs = [j.clone_pending() for j in run.jobs]
+    if reference:
+        eng = reference_run(run.spec, jobs, pri, queue_window=PAI_WINDOW,
+                            predictor=RuntimePredictor(assist=False),
+                            hooks=(seen,))
+    else:
+        eng = run_stream(run.spec, jobs, pri, queue_window=PAI_WINDOW,
+                         chunked_submit=True,
+                         predictor=RuntimePredictor(assist=False),
+                         hooks=(seen,)).engine
     return (tuple(sorted((j.job_id, j.first_start_time, j.finish_time)
                          for j in eng.completed)),
             (eng.decisions, eng.milp_calls, eng.backfills)), seen.seen

@@ -1,10 +1,14 @@
-"""Differential equivalence: optimized engine vs. retained naive reference.
+"""Differential equivalence: the engine vs. the retained naive reference.
 
-The optimized hot path (indexed pending queue, version-keyed feasibility
-cache, scratch ClusterState reuse, batch scoring) must be **bit-identical**
-to the seed's naive loop (full re-sort + linear scans + scalar scoring) —
-same completion order, same per-job start/finish times, same BatchResult
-aggregates — on every stream we can throw at it."""
+The engine's decision loop (indexed pending queue, version-keyed
+feasibility cache, scratch ClusterState reuse, batch scoring) must be
+**bit-identical** to the seed's naive loop in ``repro.sched.reference``
+(full re-sort + linear scans + scalar scoring) — same completion order, same
+per-job start/finish times, same BatchResult aggregates — on every stream we
+can throw at it."""
+import math
+import pickle
+
 import numpy as np
 import pytest
 from conftest import hypothesis_or_stubs
@@ -14,16 +18,17 @@ given, settings, st = hypothesis_or_stubs()
 from repro.core import (FaultModel, PolicyPrioritizer, make_cluster,
                         make_policy)
 from repro.core.types import Job
-from repro.sched import SchedulerEngine, get_scenario, list_scenarios, \
-    run_stream
+from repro.sched import (QuotaPrioritizer, ReferenceEngine, SchedulerEngine,
+                         get_scenario, list_scenarios, reference_run,
+                         run_stream)
 
 
 def _run(spec, jobs, policy, *, optimized, allocator="pack",
          fault_model=None, queue_window=None, backfill=True):
     pri = PolicyPrioritizer(make_policy(policy), batch=optimized)
-    engine = SchedulerEngine(spec, pri, allocator=allocator,
-                             backfill=backfill, fault_model=fault_model,
-                             queue_window=queue_window, optimized=optimized)
+    cls = SchedulerEngine if optimized else ReferenceEngine
+    engine = cls(spec, pri, allocator=allocator, backfill=backfill,
+                 fault_model=fault_model, queue_window=queue_window)
     engine.submit([j.clone_pending() for j in jobs])
     engine.run_until_complete()
     r = engine.result()
@@ -34,6 +39,10 @@ def _run(spec, jobs, policy, *, optimized, allocator="pack",
         "agg": (r.makespan, r.total_wait, r.gpu_seconds_used, r.decisions,
                 r.milp_calls, r.backfills, r.restarts),
     }
+
+
+def _times(jobs):
+    return {j.job_id: (j.start_time, j.finish_time) for j in jobs}
 
 
 @pytest.mark.parametrize("name", sorted(list_scenarios()))
@@ -72,16 +81,15 @@ def test_differential_narrow_window_and_service_driver():
     """Tiny ranking window forces heavy window churn on the indexed queue;
     the rescan-interval service driver must agree too."""
     run = get_scenario("flash-crowd").build(200, seed=9)
-    outs = []
-    for optimized in (True, False):
-        pri = PolicyPrioritizer(make_policy("fcfs"), batch=optimized)
-        sr = run_stream(run.spec, [j.clone_pending() for j in run.jobs], pri,
-                        rescan_interval=60.0, allocator="pack",
-                        queue_window=8, fault_model=run.fault_model,
-                        chunked_submit=True, optimized=optimized)
-        outs.append({j.job_id: (j.start_time, j.finish_time)
-                     for j in sr.batch.jobs})
-    assert outs[0] == outs[1]
+    sr = run_stream(run.spec, [j.clone_pending() for j in run.jobs],
+                    PolicyPrioritizer(make_policy("fcfs")),
+                    rescan_interval=60.0, allocator="pack", queue_window=8,
+                    fault_model=run.fault_model, chunked_submit=True)
+    ref = reference_run(run.spec, [j.clone_pending() for j in run.jobs],
+                        PolicyPrioritizer(make_policy("fcfs"), batch=False),
+                        allocator="pack", queue_window=8,
+                        fault_model=run.fault_model)
+    assert _times(sr.batch.jobs) == _times(ref.completed)
 
 
 def _mk_stream(seed: int, n: int = 200) -> list[Job]:
@@ -118,20 +126,21 @@ def test_differential_tenancy_wrapped_rank_window(name, policy):
     serving engine-maintained field views (incl. the new user/vc arrays) to
     their base — the wrapped fields path must schedule bit-identically to
     the naive scalar path."""
-    from repro.sched import run_stream as _rs, wrap_tenancy
+    from repro.sched import wrap_tenancy
 
     run = get_scenario(name).build(160, seed=7)
-    outs = []
-    for optimized in (True, False):
-        pri = PolicyPrioritizer(make_policy(policy), batch=optimized)
-        pri = wrap_tenancy(pri, run.sla_users, run.vc_quotas)
-        sr = _rs(run.spec, [j.clone_pending() for j in run.jobs], pri,
-                 rescan_interval=60.0, allocator="pack",
-                 fault_model=run.fault_model, chunked_submit=True,
-                 optimized=optimized)
-        outs.append({j.job_id: (j.start_time, j.finish_time)
-                     for j in sr.batch.jobs})
-    assert outs[0] == outs[1]
+
+    def pri(batch):
+        return wrap_tenancy(PolicyPrioritizer(make_policy(policy),
+                                              batch=batch),
+                            run.sla_users, run.vc_quotas)
+    sr = run_stream(run.spec, [j.clone_pending() for j in run.jobs],
+                    pri(True), rescan_interval=60.0, allocator="pack",
+                    fault_model=run.fault_model, chunked_submit=True)
+    ref = reference_run(run.spec, [j.clone_pending() for j in run.jobs],
+                        pri(False), allocator="pack",
+                        fault_model=run.fault_model)
+    assert _times(sr.batch.jobs) == _times(ref.completed)
 
 
 def test_rank_window_fields_match_rank_for_all_policies():
@@ -176,3 +185,87 @@ def test_differential_property(seed, scenario, policy):
     ref = _run(run.spec, run.jobs, policy, optimized=False,
                fault_model=run.fault_model)
     assert opt == ref
+
+
+# ---- the reference loop through save_state / load_state -------------------
+
+
+def _signature(engine):
+    return (tuple(sorted((j.job_id, j.start_time, j.finish_time, j.restarts)
+                         for j in engine.completed)),
+            (engine.decisions, engine.milp_calls, engine.backfills))
+
+
+def test_fast_blob_switched_off_loads_as_reference_engine():
+    """The engine's saved state with ``optimized`` switched off and the
+    cluster's cache disabled (what a reference replay does) restores as a
+    ``ReferenceEngine`` and from there schedules exactly as a reference run
+    over the whole stream."""
+    run = get_scenario("fault-storm").build(160, seed=4)
+    jobs = sorted(run.jobs, key=lambda j: j.submit_time)
+    half = len(jobs) // 2
+    fast = SchedulerEngine(run.spec, PolicyPrioritizer(make_policy("sjf")),
+                           allocator="pack", fault_model=run.fault_model)
+    fast.submit([j.clone_pending() for j in jobs[:half]])
+    fast.step(jobs[half].submit_time - 1.0)
+    assert fast.running and fast.decisions
+    state = pickle.loads(fast.save_state())
+    state["optimized"] = False
+    state["cluster"].cache_enabled = False
+    eng = SchedulerEngine.load_state(pickle.dumps(state))
+    assert type(eng) is ReferenceEngine and eng._pindex is None
+    eng.submit([j.clone_pending() for j in jobs[half:]])
+    eng.run_until_complete()
+    ref = reference_run(run.spec, [j.clone_pending() for j in jobs],
+                        PolicyPrioritizer(make_policy("sjf"), batch=False),
+                        allocator="pack", fault_model=run.fault_model)
+    assert eng.done and _signature(eng) == _signature(ref)
+
+
+def test_reference_engine_round_trips_as_itself():
+    """A ``ReferenceEngine`` saved mid-stream restores as a
+    ``ReferenceEngine`` (uncached cluster, no pending index) and finishes
+    exactly as a run that was never cut."""
+    run = get_scenario("fault-storm").build(120, seed=6)
+
+    def fresh():
+        eng = ReferenceEngine(run.spec,
+                              PolicyPrioritizer(make_policy("fcfs"),
+                                                batch=False),
+                              allocator="pack", fault_model=run.fault_model)
+        eng.submit([j.clone_pending() for j in run.jobs])
+        return eng
+    straight = fresh()
+    straight.run_until_complete()
+    cut = fresh()
+    cut.step(math.inf, max_events=40)
+    back = SchedulerEngine.load_state(cut.save_state())
+    assert type(back) is ReferenceEngine and back._pindex is None
+    assert not back.cluster.cache_enabled
+    back.run_until_complete()
+    assert back.done and _signature(back) == _signature(straight)
+
+
+def test_fast_blob_reattaches_the_quota_gate():
+    """A saved state that carries a quota gate restores with the gate
+    attached as a hook: its usage follows every later start and finish."""
+    run = get_scenario("multi-tenant").build(160, seed=3)
+    pri = QuotaPrioritizer(PolicyPrioritizer(make_policy("fcfs")),
+                           run.vc_quotas)
+    eng = SchedulerEngine(run.spec, pri, allocator="pack",
+                          fault_model=run.fault_model, hooks=(pri,))
+    eng.submit([j.clone_pending() for j in run.jobs])
+    eng.step(math.inf, max_events=30)
+    back = SchedulerEngine.load_state(eng.save_state())
+    gate = back.prioritizer
+    assert gate is not pri and back.hooks == [gate]
+    assert gate._usage, "the blob's usage travels with the gate"
+    moves, last = 0, dict(gate._usage)
+    while back.step(math.inf, max_events=1):
+        expect = {}
+        for job, *_ in back.running.values():
+            expect[job.vc] = expect.get(job.vc, 0) + job.num_gpus
+        assert gate._usage == expect
+        moves += gate._usage != last
+        last = dict(gate._usage)
+    assert back.done and moves > 0

@@ -19,15 +19,13 @@ given, settings, st = hypothesis_or_stubs()
 
 def fresh_engine(run, *, allocator="pack", degradation=None):
     """A drain-mode engine wired exactly like the service loop wires one
-    (tenancy wrap + incremental quota hook + engine back-reference)."""
+    (tenancy wrap + incremental quota hook)."""
     pri = wrap_tenancy(PolicyPrioritizer(make_policy("fcfs")),
                        run.sla_users, run.vc_quotas)
     hooks = (pri,) if isinstance(pri, QuotaPrioritizer) else ()
     eng = SchedulerEngine(run.spec, pri, allocator=allocator,
                           fault_model=run.fault_model, hooks=hooks,
                           degradation=degradation)
-    if isinstance(pri, QuotaPrioritizer):
-        pri.engine = eng
     eng.submit([j.clone_pending() for j in run.jobs])
     return eng
 

@@ -133,8 +133,6 @@ def test_single_cluster_hash_identical_to_bare_engine(name):
         hooks = (pri,) if isinstance(pri, QuotaPrioritizer) else ()
         eng = SchedulerEngine(run.spec, pri, allocator="pack",
                               fault_model=run.fault_model, hooks=hooks)
-        if isinstance(pri, QuotaPrioritizer):
-            pri.engine = eng
         eng.submit([j.clone_pending() for j in run.jobs])
         eng.drain()
         bare = {j.job_id: (j.start_time, j.finish_time, j.restarts)

@@ -317,23 +317,3 @@ def test_deep_scorer_inert_below_window():
     assert deep.rank(jobs, cluster, now=100.0) \
         == base.rank(jobs, cluster, now=100.0)
     assert sc.compiled_buckets == ()
-
-
-# ------------------------------------------------- deep lookahead shrink ----
-
-
-def test_deep_lookahead_inert_below_threshold():
-    """deep_lookahead_k only engages beyond deep_queue_threshold pending
-    jobs: a shallow stream is bit-identical with and without it."""
-    run = get_scenario("steady").build(300, seed=0)
-
-    def sig(**kw):
-        pri = PolicyPrioritizer(make_policy("fcfs"))
-        eng = _stream(SchedulerEngine(run.spec, pri, allocator="pack",
-                                      fault_model=run.fault_model, **kw),
-                      run.jobs)
-        return (tuple(sorted((j.job_id, j.finish_time)
-                             for j in eng.completed)),
-                eng.decisions, eng.backfills)
-
-    assert sig() == sig(deep_lookahead_k=2, deep_queue_threshold=4096)

@@ -254,15 +254,16 @@ def test_chunked_scenario_service_equals_upfront():
 
 
 def test_naive_reference_matches_seed_golden():
-    """The retained naive engine path (optimized=False, scalar scoring) is
+    """The retained naive loop (``ReferenceEngine``, scalar scoring) is
     the seed implementation and must still hit the golden aggregates."""
+    from repro.sched import reference_run
+
     key = ("helios", 96, 0, "fcfs", "milp", True, False)
     trace, n, seed, policy, allocator, backfill, _ = key
     jobs = generate_trace(trace, n, seed=seed)
-    sim = Simulator(make_cluster(trace), allocator=allocator,
-                    backfill=backfill, optimized=False)
-    r = sim.run_batch([j.clone_pending() for j in jobs],
-                      PolicyPrioritizer(make_policy(policy), batch=False))
+    r = reference_run(make_cluster(trace), [j.clone_pending() for j in jobs],
+                      PolicyPrioritizer(make_policy(policy), batch=False),
+                      allocator=allocator, backfill=backfill).result()
     got = (r.makespan, r.total_wait, r.gpu_seconds_used, r.decisions,
            r.milp_calls, r.backfills, r.restarts)
     assert got == SEED_GOLDENS[key]

@@ -118,50 +118,44 @@ def test_quota_usage_tracks_start_finish_requeue():
 
 
 class _UsageAuditor(EngineHooks):
-    """Asserts, at every engine tick, that the hook-fed incremental usage
-    equals a fresh O(running) recompute from the engine's running set."""
+    """Asserts that the hook-fed incremental usage equals a fresh
+    O(running) recompute from the engine's running set at every engine
+    tick, and right after every ranking — so every gate decision read the
+    recompute's usage."""
 
     def __init__(self, pri):
         self.pri = pri
         self.checked = 0
+        self.decisions = 0
 
-    def on_tick(self, now, engine):
+    def _check(self, engine):
         expect = {}
         for job, *_ in engine.running.values():
             expect[job.vc] = expect.get(job.vc, 0) + job.num_gpus
         assert self.pri._usage == expect
+
+    def on_tick(self, now, engine):
+        self._check(engine)
         self.checked += 1
 
+    def on_decision(self, jobs, order, now, engine):
+        self._check(engine)
+        self.decisions += 1
 
-def test_quota_incremental_matches_recompute_every_tick():
+
+@pytest.mark.parametrize("scenario", ["multi-tenant", "fault-storm"])
+def test_quota_incremental_matches_recompute_every_tick(scenario):
     """The incremental usage dict equals the O(running) recompute after
-    every processed event batch, including fault-driven requeues."""
-    run = get_scenario("fault-storm").build(64, seed=2)
-    pri = QuotaPrioritizer(PolicyPrioritizer(make_policy("fcfs")),
-                           {0: 0.25, 1: 0.25, 2: 0.25, 3: 0.25})
+    every processed event batch and at every decision, including
+    fault-driven requeues."""
+    run = get_scenario(scenario).build(64, seed=2)
+    quotas = run.vc_quotas or {0: 0.25, 1: 0.25, 2: 0.25, 3: 0.25}
+    pri = QuotaPrioritizer(PolicyPrioritizer(make_policy("fcfs")), quotas)
     auditor = _UsageAuditor(pri)
     run_stream(run.spec, [j.clone_pending() for j in run.jobs], pri,
                allocator="pack", fault_model=run.fault_model,
                hooks=(auditor,))
-    assert auditor.checked > 0
-
-
-@pytest.mark.parametrize("scenario", ["multi-tenant", "fault-storm"])
-def test_quota_incremental_differential(scenario):
-    """Equivalence pin (ROADMAP perf round-2 item c): the incremental gate
-    schedules bit-identically to the O(running)-per-rank recompute path."""
-    run = get_scenario(scenario).build(120, seed=9)
-    quotas = run.vc_quotas or {0: 0.25, 1: 0.25, 2: 0.25, 3: 0.25}
-    outs = []
-    for incremental in (True, False):
-        pri = QuotaPrioritizer(PolicyPrioritizer(make_policy("fcfs")),
-                               quotas, incremental=incremental)
-        sr = run_stream(run.spec, [j.clone_pending() for j in run.jobs],
-                        pri, allocator="pack", fault_model=run.fault_model,
-                        chunked_submit=True)
-        outs.append({j.job_id: (j.start_time, j.finish_time, j.restarts)
-                     for j in sr.batch.jobs})
-    assert outs[0] == outs[1]
+    assert auditor.checked > 0 and auditor.decisions > 0
 
 
 def test_wrap_tenancy_composition():
@@ -291,8 +285,8 @@ def test_pai_stream_deep_and_gated(pai_fast):
 
 def test_pai_stream_fast_engine_equals_reference_loop(pai_fast):
     """The fast engine taking the gate's arrays schedules exactly as the
-    reference loop (``optimized=False``), which ranks through ``rank``."""
-    assert pai_quota_stream(optimized=False)[0] == pai_fast[0]
+    reference loop (``ReferenceEngine``), which ranks through ``rank``."""
+    assert pai_quota_stream(reference=True)[0] == pai_fast[0]
 
 
 def test_pai_stream_array_order_equals_list_order(pai_fast, monkeypatch):

@@ -83,17 +83,20 @@ class RLPrioritizer:
             self.record = record
 
     def rank(self, jobs: list[Job], cluster: ClusterState, now: float) -> list[int]:
-        return self._rank(jobs, cluster, now, None)
+        return self._rank(jobs, cluster, now, None).tolist()
 
     def rank_window(self, jobs: list[Job], cluster: ClusterState, now: float,
-                    fields) -> list[int]:
+                    fields) -> np.ndarray:
         """``rank`` over the engine's contiguous ``WindowFields`` views: the
         FBM feature matrix is built by shape and by column instead of the
         O(window * 17) scalar loop — bit-identical features, hence
-        bit-identical actions and ranking (differential-pinned)."""
+        bit-identical actions and ranking (differential-pinned).  Returns
+        the permutation as the ``np.intp`` array it is built as, which the
+        engine's fast path indexes as it is; ``rank`` gives the same values
+        as the protocol's list."""
         return self._rank(jobs, cluster, now, fields)
 
-    def _rank(self, jobs, cluster, now, fields) -> list[int]:
+    def _rank(self, jobs, cluster, now, fields) -> np.ndarray:
         n = min(len(jobs), MAX_QUEUE_SIZE)
         deep = self.deep_scorer is not None and len(jobs) > MAX_QUEUE_SIZE
         tail_logits = None
@@ -128,20 +131,20 @@ class RLPrioritizer:
         action, logits = self.agent.act(ov, cv, mask, explore=self.explore,
                                         record=self.explore and self.record)
         with span("rank.order"):
-            order = list(np.argsort(-logits[:n], kind="stable"))
-            if action < n:
-                order.remove(action)
-                order.insert(0, action)
+            head = np.argsort(-logits[:n], kind="stable")
+            if action < n and head[0] != action:
+                # a sampled (explore) action off the greedy head moves to
+                # the front; in greedy mode the head already is the action
+                head = np.concatenate(([action], head[head != action]))
             if tail_logits is not None:
                 # deep-window mode: tail ordered by the bucketed scorer
                 # (stable argsort keeps FIFO among exact ties)
-                order += [int(n + i)
-                          for i in np.argsort(-tail_logits, kind="stable")]
+                tail = n + np.argsort(-tail_logits, kind="stable")
             else:
                 # jobs beyond the fixed-size window keep FIFO order at the
                 # tail
-                order += list(range(n, len(jobs)))
-        return order
+                tail = np.arange(n, len(jobs))
+            return np.concatenate((head, tail), dtype=np.intp)
 
     def observe_finish(self, job: Job) -> None:
         if self.stream_stats is not None:

@@ -92,7 +92,10 @@ class Prioritizer(Protocol):
     ``rank_window(jobs, cluster, now, fields)`` accepting a
     :class:`WindowFields`; the engine uses it when present and falls back
     to ``rank`` otherwise (wrapper prioritizers that reorder sublists keep
-    working unchanged)."""
+    working unchanged).  ``rank`` returns a list; ``rank_window`` may
+    return the permutation as an ``np.intp`` array instead (the RL
+    prioritizer and the quota gate do), which the engine and its hooks
+    index and never mutate as a list."""
 
     use_estimates: bool
 

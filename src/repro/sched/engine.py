@@ -187,11 +187,15 @@ class EngineHooks:
         checkpoint.  Fires right after the matching ``on_start``."""
         ...
 
-    def on_decision(self, jobs: list[Job], order: list[int], now: float,
+    def on_decision(self, jobs: list[Job], order: list[int] | np.ndarray,
+                    now: float,
                     engine: "SchedulerEngine") -> None:
         """One prioritizer decision: ``jobs`` is the ranking window handed
         to the prioritizer, ``order`` its returned permutation (index 0 =
-        scheduled first).  Fired on every engine loop right after ranking —
+        scheduled first): a list, or on the fast path an ``np.intp`` array
+        where the prioritizer's ``rank_window`` returns one (the RL
+        prioritizer, the quota gate), so hooks index it and do not mutate
+        it as a list.  Fired on every engine loop right after ranking —
         this is how the streaming RL episode cutter (``repro.rl``) aligns
         rewards with recorded policy steps.  Observational only."""
         ...
@@ -1319,7 +1323,8 @@ class SchedulerEngine:
         return False
 
     # ---------------------------------------------------------- scheduling ----
-    def _fire_decision(self, queue: list[Job], order: list[int]) -> None:
+    def _fire_decision(self, queue: list[Job],
+                       order: list[int] | np.ndarray) -> None:
         """Notify decision observers.  ``getattr``-guarded because hooks are
         duck-typed (pre-existing observers may not define ``on_decision``)."""
         for h in self.hooks:

@@ -72,7 +72,7 @@ def helios_cluster():
 PAI_WINDOW = 320
 
 
-def pai_quota_stream(*, reference=False):
+def pai_quota_stream(*, reference=False, quotas=True):
     """A small stream shaped like the ``pai-quota`` cell: the
     ``multi-tenant`` scenario (the Alibaba PAI slice over T4, P100 and
     V100; four VCs with 55/25/12/8% of the demand and 25% quotas) with 360
@@ -85,7 +85,9 @@ def pai_quota_stream(*, reference=False):
     handed, the window's rows, how many of them belong to VCs over quota
     (the rows it demotes) and how many VCs are over quota.  With
     ``reference`` the stream runs on the reference loop
-    (``repro.sched.reference_run``) instead of ``run_stream``."""
+    (``repro.sched.reference_run``) instead of ``run_stream``; with
+    ``quotas`` False the RL prioritizer ranks the stream without the gate
+    (no VC is then ever over quota)."""
     from repro.core.agent import PPOAgent
     from repro.core.env import RLPrioritizer
     from repro.kernels.batch_score import BucketedScorer
@@ -100,7 +102,7 @@ def pai_quota_stream(*, reference=False):
         def on_decision(self, jobs, order, now, engine):
             pri = engine.prioritizer
             total = max(engine.cluster.provisioned_gpu_totals()[0], 1)
-            over = {vc for vc, q in pri.quotas.items()
+            over = {vc for vc, q in getattr(pri, "quotas", {}).items()
                     if pri._usage.get(vc, 0) / total > q}
             self.seen.append((type(order), len(jobs),
                               sum(j.vc in over for j in jobs), len(over)))
@@ -112,7 +114,7 @@ def pai_quota_stream(*, reference=False):
     pri = wrap_tenancy(
         RLPrioritizer(agent, explore=False,
                       deep_scorer=BucketedScorer(agent.params["actor"])),
-        vc_quotas=run.vc_quotas)
+        vc_quotas=run.vc_quotas if quotas else None)
     seen = Partitions()
     jobs = [j.clone_pending() for j in run.jobs]
     if reference:

@@ -173,7 +173,8 @@ def test_build_state_fields_path_identical(helios_jobs, helios_cluster):
 
 def test_rl_prioritizer_rank_window_matches_rank():
     """The engine hands RLPrioritizer.rank_window its field views; the
-    returned permutation (and hence the schedule) must equal rank()'s."""
+    returned permutation (and hence the schedule) must equal rank()'s,
+    value for value: an ``np.intp`` array against the protocol's list."""
     from repro.core.agent import PPOAgent, PPOConfig
     from repro.core.env import RLPrioritizer
 
@@ -183,7 +184,9 @@ def test_rl_prioritizer_rank_window_matches_rank():
     pri = RLPrioritizer(PPOAgent(PPOConfig(seed=3)), explore=False)
     a = pri.rank(jobs, c, 1e4)
     b = pri.rank_window(jobs, c, 1e4, fields)
-    assert a == b
+    assert type(a) is list and all(type(i) is int for i in a)
+    assert isinstance(b, np.ndarray) and b.dtype == np.intp
+    assert b.tolist() == a
 
 
 def test_rl_stream_rank_window_schedule_identical():

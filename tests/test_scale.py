@@ -9,11 +9,14 @@ aggregates.
 import numpy as np
 import pytest
 
+from conftest import PAI_WINDOW, pai_quota_stream
 from repro.core import PolicyPrioritizer, make_policy
 from repro.core.agent import PPOAgent
 from repro.core.cluster import ClusterState
 from repro.core.env import RLPrioritizer
+from repro.core.features import MAX_QUEUE_SIZE
 from repro.core.milp import choose_allocation
+from repro.core.prioritizer import WindowFields
 from repro.core.types import ClusterSpec, Job, NodeSpec
 from repro.fed import run_fleet
 from repro.fed.scenarios import FLEET_SCENARIOS
@@ -317,3 +320,116 @@ def test_deep_scorer_inert_below_window():
     assert deep.rank(jobs, cluster, now=100.0) \
         == base.rank(jobs, cluster, now=100.0)
     assert sc.compiled_buckets == ()
+
+
+def _list_order(action, logits, n, tail_logits, rows):
+    """The ranking order as a Python list, composed element by element:
+    the plain reference for ``RLPrioritizer._rank``'s numpy order."""
+    order = list(np.argsort(-logits[:n], kind="stable"))
+    if action < n:
+        order.remove(action)
+        order.insert(0, action)
+    if tail_logits is not None:
+        order += [int(n + i)
+                  for i in np.argsort(-tail_logits, kind="stable")]
+    else:
+        order += list(range(n, rows))
+    return order
+
+
+def _tied_logits(seed, size, levels):
+    """Seeded float32 logits on a few integer levels: many exact ties."""
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, levels, size=size).astype(np.float32)
+
+
+class _TiedActor:
+    """Stands in for the PPO agent: the same tied logits over the head
+    rows at every call (masked rows below), and either the greedy head or
+    an action off it, as the sampling (explore) path can return."""
+
+    def __init__(self, rows, explore, seed):
+        n = min(rows, MAX_QUEUE_SIZE)
+        self.logits = np.full(MAX_QUEUE_SIZE, -1e9, dtype=np.float32)
+        self.logits[:n] = _tied_logits(seed, n, 8)
+        head = np.argsort(-self.logits[:n], kind="stable")
+        self.head = int(head[0])
+        self.action = int(head[n // 2]) if explore else self.head
+
+    def act(self, ov, cv, mask, explore=True, record=True):
+        return self.action, self.logits.copy()
+
+
+class _TiedScorer:
+    """Stands in for the deep scorer: tied logits for the tail rows."""
+
+    def __init__(self, seed):
+        self.seed = seed
+        self.calls = []
+
+    def score(self, rows):
+        self.calls.append(len(rows))
+        return _tied_logits(self.seed, len(rows), 16)
+
+
+@pytest.mark.parametrize("mode", ["greedy", "explore"])
+@pytest.mark.parametrize("deep", [False, True], ids=["fifo-tail", "deep-tail"])
+@pytest.mark.parametrize("rows", [64, 300, 4096])
+def test_rank_order_array_equals_list_composition(rows, deep, mode):
+    """``_rank`` builds the permutation as one ``np.intp`` array: value for
+    value the list composition, with ties kept in row order in the head
+    and in the scored tail, and a sampled action off the greedy head moved
+    to the front.  ``rank_window`` hands the engine that array, ``rank``
+    the protocol's list of plain ints."""
+    jobs, cluster = _mk_jobs(rows), _mk_cluster()
+    explore = mode == "explore"
+    agent = _TiedActor(rows, explore, seed=rows)
+    scorer = _TiedScorer(seed=rows + 1) if deep else None
+    pri = RLPrioritizer(agent, explore=explore, deep_scorer=scorer)
+    got = pri.rank_window(jobs, cluster, 500.0, WindowFields.from_jobs(jobs))
+    listed = pri.rank(jobs, cluster, 500.0)
+
+    n = min(rows, MAX_QUEUE_SIZE)
+    scored = deep and rows > n
+    tail = _tied_logits(rows + 1, rows - n, 16) if scored else None
+    want = [int(i) for i in _list_order(agent.action, agent.logits, n, tail,
+                                        rows)]
+    assert isinstance(got, np.ndarray) and got.dtype == np.intp
+    assert got.tolist() == want
+    assert type(listed) is list and all(type(i) is int for i in listed)
+    assert listed == want
+    assert sorted(want) == list(range(rows))
+    assert want[0] == agent.action
+    assert (agent.action != agent.head) == explore
+    if scorer is not None:
+        assert scorer.calls == ([rows - n] * 2 if scored else [])
+
+
+@pytest.fixture(scope="module")
+def deep_rl_stream():
+    """The PAI-shaped deep-window stream ranked by the RL prioritizer
+    alone (no quota gate) on the fast engine."""
+    return pai_quota_stream(quotas=False)
+
+
+@pytest.mark.parametrize("other", ["list_order", "reference_loop"])
+def test_deep_rl_stream_array_order_schedules_alike(deep_rl_stream, other,
+                                                    monkeypatch):
+    """The fast engine taking ``RLPrioritizer.rank_window``'s arrays over
+    windows past the actor's slots schedules exactly as the same engine
+    handed the order as a list, and as the reference loop, which ranks
+    through ``rank``."""
+    sig, seen = deep_rl_stream
+    assert max(rows for _, rows, _, _ in seen) == PAI_WINDOW
+    assert {form for form, *_ in seen} == {np.ndarray}
+    if other == "list_order":
+        ranked = RLPrioritizer.rank_window
+
+        def as_list(self, *args):
+            return ranked(self, *args).tolist()
+        monkeypatch.setattr(RLPrioritizer, "rank_window", as_list)
+        got, seen_list = pai_quota_stream(quotas=False)
+        assert {form for form, *_ in seen_list} == {list}
+    else:
+        got = pai_quota_stream(reference=True, quotas=False)[0]
+    assert got == sig
